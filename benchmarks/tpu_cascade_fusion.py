@@ -5,7 +5,8 @@ Quantifies exactly what the paper's cascade eliminates, in TPU terms:
   * HBM bytes moved per inference (intermediates stay in VMEM when fused),
   * kernel launches (1 vs L),
   * modeled end-to-end latency on the v5e target (overhead-aware model),
-  * measured CPU interpret-mode equality of outputs (bit-exact INT8).
+  * equality of the fused kernel's outputs with the oracle (bit-exact
+    INT8), compiled on a TPU and interpreted on a CPU.
 """
 from __future__ import annotations
 
@@ -17,6 +18,7 @@ from repro.core import tpu_model
 from repro.core.fusion_planner import plan, shapes_from_model
 from repro.core.layerspec import REALISTIC_WORKLOADS, synthetic_mlp
 from repro.kernels.cascade_mlp import cascade_mlp, cascade_mlp_ref, mlp_unfused
+from repro.launch import platform
 from repro.quant import quantize_mlp
 
 
@@ -51,7 +53,7 @@ def main() -> dict:
         t_u = tpu_model.unfused_chain_time_s(shapes) * 1e6
         xq = jnp.clip(jnp.round(jnp.asarray(xf) / 2.0 ** qmlp.e_in),
                       -128, 127).astype(jnp.int8)
-        fused_out = cascade_mlp(xq, qmlp, interpret=True)
+        fused_out = cascade_mlp(xq, qmlp, interpret=platform.interpret())
         ref_out = cascade_mlp_ref(xq, qmlp)
         exact = bool(jnp.all(fused_out == ref_out))
         print(f"{name},{hbm_f},{hbm_u},1,{len(shapes)},"

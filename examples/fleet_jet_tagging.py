@@ -1,7 +1,7 @@
 """Multi-tenant fleet serving (beyond the paper — repro.core.tenancy).
 
 Trains a DeepSets jet tagger, deploys it behind a ``FleetServer`` with 4
-replica kernels (interpret-mode Pallas on this CPU container), dispatches a
+replica kernels (compiled Pallas on a TPU, interpreted on a CPU), dispatches a
 micro-batched event stream sliced across the replicas (scatter/gather),
 and reports batched p50/p99 + events/sec with per-replica scatter
 accounting, next to the Tier-A modeled multi-tenant schedule on the VEK280

@@ -2,8 +2,9 @@
 
   1. Tier A — the paper itself: run the μ-ORCA DSE on a jet-tagging model
      and read the overhead-aware latency estimate for the VEK280.
-  2. Kernels — execute the fused cascade-MLP Pallas kernel (interpret mode
-     on CPU) and check it against the pure-jnp oracle bit-for-bit.
+  2. Kernels — execute the fused cascade-MLP Pallas kernel (compiled on a
+     TPU, interpreted on a CPU) and check it against the pure-jnp oracle
+     bit-for-bit.
   3. Substrate — build one of the assigned LM architectures (reduced size),
      run a train step and a decode step.
 
@@ -24,6 +25,7 @@ print(f"    -> {result.latency_ns / 1e3:.2f} us vs the 1 us budget; "
       f"{result.cascade_edges} cascade edges")
 
 # --- 2. the fused cascade kernel ----------------------------------------------
+from repro.launch import platform
 from repro.quant import quantize_mlp
 from repro.kernels.cascade_mlp import cascade_mlp, cascade_mlp_ref
 
@@ -35,7 +37,7 @@ x = rng.normal(0, 1, (64, 16)).astype(np.float32)
 qmlp = quantize_mlp(ws, bs, [True, True, False], x)
 xq = jnp.clip(jnp.round(jnp.asarray(x) / 2.0 ** qmlp.e_in),
               -128, 127).astype(jnp.int8)
-out = cascade_mlp(xq, qmlp, interpret=True)
+out = cascade_mlp(xq, qmlp, interpret=platform.interpret())
 ref = cascade_mlp_ref(xq, qmlp)
 print(f"[2] fused cascade kernel == oracle: {bool(jnp.all(out == ref))} "
       f"(INT8, bit-exact)")

@@ -1,26 +1,29 @@
 """μs-scale jet-tagging serving driver — the paper's deployment scenario.
 
 Trains a small MLP or DeepSets tagger on the synthetic jet stream, quantizes
-it to the paper's INT8 power-of-two scheme, deploys it behind the batching
-``JetServer`` running the FUSED cascade Pallas kernel (interpret mode on this
-CPU container), and reports:
+it to the paper's INT8 power-of-two scheme, deploys it behind a
+``FleetServer`` running the FUSED cascade Pallas kernel (compiled on a TPU;
+interpret mode only where the backend is a CPU, see
+:mod:`repro.launch.platform`), and reports:
 
+  * the device it serves on (platform, device kind, device count),
   * classification accuracy float vs INT8 (quantization cost),
-  * measured wall-clock latency percentiles on this host,
+  * measured wall-clock latency percentiles, labelled with the device, for
+    a micro-batched stream and for single events sent one at a time,
   * the Tier-B modeled latency on the TPU target (fused vs per-layer),
-  * the Tier-A μ-ORCA DSE latency for the same network on the VEK280
-    (the paper's own deployment target), with its mapping summary.
+  * the Tier-A μ-ORCA modeled latency for the same network on the VEK280
+    (the paper's own deployment target).
 
 Multi-tenant serving (beyond the paper — see repro.core.tenancy): with
-``--replicas N`` the model is deployed behind a ``FleetServer`` with N
-replica kernels; ``--mix a,b`` deploys several models side by side, the
-software analogue of packing tenant rectangles onto the shared AIE array.
-Events are dispatched *micro-batched*: sliced across replicas, scattered,
-gathered back with batched percentiles. The driver then also reports the
-Tier-A modeled multi-tenant schedule (replica packing, shared PLIO budget)
-with both the serial R/latency events/sec and the pipelined headline —
-initiation interval II, sustained events/sec, and the contended pipelined
-throughput-frontier point the deployment should be measured against.
+``--replicas N`` each model gets N replica kernels; ``--mix a,b`` deploys
+several models side by side, the software analogue of packing tenant
+rectangles onto the shared AIE array. Events are dispatched *micro-batched*:
+sliced across replicas, scattered, gathered back with batched percentiles.
+The driver then also reports the Tier-A modeled multi-tenant schedule
+(replica packing, shared PLIO budget) with both the serial R/latency
+events/sec and the pipelined headline — initiation interval II, sustained
+events/sec, and the contended pipelined throughput-frontier point the
+deployment should be measured against.
 
 Open-loop load and SLOs (the observatory half): ``--arrivals`` replaces
 the back-to-back batched dispatch with a seeded wall-clock arrival
@@ -40,17 +43,18 @@ The driver exits 1 when any tenant's error budget is exhausted, and
 from __future__ import annotations
 
 import argparse
-import time
+from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import dse, layerspec
+from repro.core import layerspec
 from repro.data import JetConfig, jet_batch
+from repro.launch import platform
 from repro.models import deepsets as ds
 from repro.models import mlp as mlp_lib
-from repro.serve import JetServer
+from repro.serve import ServeStats
 from repro.serve.fleet import FleetServer, TenantSpec
 
 MODELS = {
@@ -61,6 +65,8 @@ MODELS = {
     "deepsets-64": dict(kind="deepsets", M=64, F=21,
                         phi=[64, 64, 64], rho=[64, 10]),
 }
+#: Events per tenant sent one at a time after the micro-batched stream.
+SINGLE_EVENTS = 8
 SPECS = {"jsc-m": layerspec.jsc_m, "jsc-xl": layerspec.jsc_xl,
          "deepsets-32": layerspec.deepsets_32,
          "deepsets-64": layerspec.deepsets_64}
@@ -120,40 +126,6 @@ def _prepare(name: str, *, train_steps: int, replicas: int, mode: str) -> dict:
                 acc_float=_accuracy(f_fn, jc))
 
 
-def _serve_single(prep: dict, args) -> None:
-    """Original single-instance deployment (one JetServer)."""
-    t = prep["tenant"]
-    server = JetServer(t.qmlp, rho=t.rho, agg=t.agg, mode=args.mode,
-                       interpret=True)
-    x, y = jet_batch(prep["jc"], args.events, 999)
-    xq = np.clip(np.round(x / 2.0 ** prep["e_in"]), -128, 127).astype(np.int8)
-    t0 = time.perf_counter()
-    correct = 0
-    for i in range(args.events):
-        out = server.infer(xq[i])
-        pred = int(np.argmax(out[..., :prep["n_classes"]]))
-        correct += int(pred == y[i])
-    wall = time.perf_counter() - t0
-    acc_q = correct / args.events
-    server.close()
-
-    print(f"\n[serve] {t.name}: float acc {prep['acc_float']:.3f}, "
-          f"INT8 acc {acc_q:.3f}")
-    print(f"[serve] measured (CPU interpret): "
-          f"p50 {server.stats.percentile(50):.0f} us, "
-          f"p99 {server.stats.percentile(99):.0f} us, "
-          f"{args.events / wall:.0f} events/s")
-    mdl = server.modeled_latency_us()
-    print(f"[serve] modeled TPU-v5e latency: fused {mdl['fused_us']:.2f} us"
-          f" vs per-layer {mdl['unfused_us']:.2f} us"
-          f" ({mdl['speedup']:.2f}x from cascade-analogue fusion)")
-
-    spec = SPECS[t.name]()
-    r = dse.explore(spec)
-    print(f"[serve] Tier-A μ-ORCA DSE on VEK280: {r.latency_ns:.0f} ns "
-          f"({r.latency_ns / 1e3:.2f} us) — {r.summary()}")
-
-
 def _report_telemetry(fleet: FleetServer, snap: dict, args) -> None:
     """Persist the metrics snapshot and print the end-of-run summary."""
     drift = snap.get("drift", {})
@@ -186,8 +158,9 @@ def _report_telemetry(fleet: FleetServer, snap: dict, args) -> None:
 
 def _check_drift_gate(snap: dict, gate: float) -> None:
     """Exit nonzero when the model-path (Tier-A vs Tier-S) MAPE exceeds the
-    gate. serve.* drift is never gated: interpret-mode CPU wall clock sits
-    orders of magnitude above the modeled VEK280 by construction."""
+    gate. serve.* drift is never gated: it compares wall clock measured on
+    whatever device serves (a TPU, or a CPU interpreting the kernels) with
+    the modeled VEK280, so it tracks relative drift, not accuracy."""
     drift = {m: d for m, d in snap.get("drift", {}).items()
              if m.startswith("model.") and d.get("mape") is not None}
     if not drift:
@@ -213,13 +186,12 @@ def _check_drift_gate(snap: dict, gate: float) -> None:
 
 
 def _drive_open_loop(fleet: FleetServer, name: str, prep: dict, xq, y,
-                     args) -> None:
+                     args) -> dict:
     """Offer the tenant's event stream on the --arrivals schedule."""
     from repro.serve import workload
     spec = args.arrival_spec
     dr = workload.drive(fleet, list(xq), spec, tenant=name, seed=args.seed)
-    for r in dr.requests:
-        r.event.wait(timeout=120)
+    outputs = [r.wait(timeout=120) for r in dr.requests]
     print(f"[fleet] {name}: {spec.describe()} -> offered {dr.offered} "
           f"({dr.offered_eps:.0f}/s), admitted {dr.admitted}, "
           f"shed {dr.shed}, driver lag {dr.lag_s * 1e3:.1f} ms")
@@ -236,7 +208,10 @@ def _drive_open_loop(fleet: FleetServer, name: str, prep: dict, xq, y,
               f"{float(np.percentile(lats, 50)):.0f} us, p99 "
               f"{float(np.percentile(lats, 99)):.0f} us; queue wait p50 "
               f"{float(np.percentile(waits, 50)):.0f} us, p99 "
-              f"{float(np.percentile(waits, 99)):.0f} us")
+              f"{float(np.percentile(waits, 99)):.0f} us "
+              f"[{args.device}]")
+    return {"inputs": xq[np.asarray(dr.admitted_idx, dtype=int)],
+            "outputs": np.stack(outputs) if outputs else np.empty((0,))}
 
 
 def _report_slo(fleet: FleetServer, args) -> "object":
@@ -261,8 +236,54 @@ def _report_slo(fleet: FleetServer, args) -> "object":
     return report
 
 
-def _serve_fleet(preps: dict, args) -> None:
-    """Multi-tenant deployment: FleetServer over R replicas per tenant."""
+def _serve_closed_loop(fleet: FleetServer, name: str, prep: dict, xq, y,
+                       args) -> dict:
+    """Serve ``args.events`` micro-batched, then a few single events."""
+    # Micro-batched dispatch: the event stream is sliced across the
+    # tenant's replicas (scatter), each slice rides one replica's batching
+    # window as a single kernel launch, results gather back in submission
+    # order — replicas run concurrently back to back instead of one round
+    # trip per event.
+    xb, xs = xq[:args.events], xq[args.events:]
+    br = fleet.infer_batch(xb, tenant=name, timeout=120)
+    preds = np.array([int(np.argmax(r[..., :prep["n_classes"]]))
+                      for r in br.results])
+    acc_q = float((preds == y[:args.events]).mean())
+    print(f"[fleet] {name}: float acc {prep['acc_float']:.3f}, "
+          f"INT8 acc {acc_q:.3f}")
+    print(f"[fleet] {name}: batched p50 {br.percentile(50):.0f} us, "
+          f"p99 {br.percentile(99):.0f} us, "
+          f"{br.throughput_eps:.0f} events/s over "
+          f"{len(br.replica_counts)} replicas "
+          f"(scatter {br.replica_counts}, total {br.n}) [{args.device}]")
+    # The trigger-stream case: each event waits for its answer before the
+    # next is sent, so every one is a batch of one.
+    single = ServeStats()
+    outputs = []
+    for x in xs:
+        req = fleet.submit(x, tenant=name)
+        outputs.append(req.wait(timeout=120))
+        single.record(req.t_submit, req.t_done)
+    if xs.size:
+        print(f"[fleet] {name}: single events p50 "
+              f"{single.percentile(50):.0f} us, p99 "
+              f"{single.percentile(99):.0f} us over {len(xs)} sent one at "
+              f"a time [{args.device}]")
+    mdl = fleet._servers[name][0].modeled_latency_us()
+    print(f"[fleet] {name}: modeled TPU-v5e latency: fused "
+          f"{mdl['fused_us']:.2f} us vs per-layer {mdl['unfused_us']:.2f} us"
+          f" ({mdl['speedup']:.2f}x from cascade-analogue fusion)")
+    return {"inputs": xq, "outputs": np.concatenate(
+                [br.results, np.stack(outputs)]) if outputs else br.results,
+            "batch": br, "single": single}
+
+
+def _serve_fleet(preps: dict, args) -> dict:
+    """Multi-tenant deployment: FleetServer over R replicas per tenant.
+
+    Returns, per tenant, the quantized inputs served, the outputs in the
+    same order, the tenant's spec and the replicas' jitted model function.
+    """
     tracer = None
     if args.trace_out:
         # A ChromeTrace carries both clocks: fleet spans are wall-clock
@@ -272,56 +293,45 @@ def _serve_fleet(preps: dict, args) -> None:
                                    "mix": ",".join(preps),
                                    "policy": args.policy})
     fleet = FleetServer([p["tenant"] for p in preps.values()],
-                        policy=args.policy, interpret=True, tracer=tracer,
+                        policy=args.policy, tracer=tracer,
                         slos=args.slo_specs,
                         admission_depth=args.admission_depth)
     print(f"\n[fleet] {fleet.num_replicas} replicas across "
           f"{len(preps)} tenant(s), policy={args.policy}")
     open_loop = (args.arrival_spec is not None
                  and args.arrival_spec.open_loop)
-    for name, prep in preps.items():
-        x, y = jet_batch(prep["jc"], args.events, 999)
-        xq = np.clip(np.round(x / 2.0 ** prep["e_in"]), -128,
-                     127).astype(np.int8)
-        if open_loop:
+    results = {}
+    try:
+        for name, prep in preps.items():
+            n = args.events + (0 if open_loop else SINGLE_EVENTS)
+            x, y = jet_batch(prep["jc"], n, 999)
+            xq = np.clip(np.round(x / 2.0 ** prep["e_in"]), -128,
+                         127).astype(np.int8)
             # Open-loop: events are *offered* on the arrival schedule and
             # the fleet's admission control decides admitted vs shed.
-            _drive_open_loop(fleet, name, prep, xq, y, args)
-            continue
-        # Micro-batched dispatch: the event stream is sliced across the
-        # tenant's replicas (scatter), each slice rides one replica's
-        # batching window as a single kernel launch, results gather back in
-        # submission order — replicas run concurrently back to back instead
-        # of one round trip per event.
-        br = fleet.infer_batch(xq, tenant=name, timeout=120)
-        preds = np.array([int(np.argmax(r[..., :prep["n_classes"]]))
-                          for r in br.results])
-        acc_q = float((preds == y[:args.events]).mean())
-        print(f"[fleet] {name}: float acc {prep['acc_float']:.3f}, "
-              f"INT8 acc {acc_q:.3f}")
-        print(f"[fleet] {name}: batched p50 {br.percentile(50):.0f} us, "
-              f"p99 {br.percentile(99):.0f} us, "
-              f"{br.throughput_eps:.0f} events/s over "
-              f"{len(br.replica_counts)} replicas "
-              f"(scatter {br.replica_counts}, total {br.n})")
-    modeled = fleet.modeled_throughput()
-    telemetry = (fleet.telemetry_snapshot()
-                 if (args.metrics_out or args.trace_out
-                     or args.drift_gate is not None) else None)
-    if tracer is not None:
-        # Append a short Tier-S run per tenant so simulator task spans land
-        # in the same trace as the fleet's dispatch/slice spans.
-        from repro.sim import run as simrun
-        for name in preps:
-            design = fleet._design(name)
-            if design is not None:
-                simrun.simulate_placement(
-                    design.placement, tenant=name,
-                    config=simrun.SimConfig(events=2), tracer=tracer)
-        tracer.save(args.trace_out)
-        print(f"[fleet] unified trace: {len(tracer.spans())} spans "
-              f"-> {args.trace_out}")
-    fleet.close()
+            drive = _drive_open_loop if open_loop else _serve_closed_loop
+            results[name] = drive(fleet, name, prep, xq, y, args)
+            results[name].update(tenant=prep["tenant"],
+                                 fn=fleet._servers[name][0]._fn)
+        modeled = fleet.modeled_throughput()
+        telemetry = (fleet.telemetry_snapshot()
+                     if (args.metrics_out or args.trace_out
+                         or args.drift_gate is not None) else None)
+        if tracer is not None:
+            # Append a short Tier-S run per tenant so simulator task spans
+            # land in the same trace as the fleet's dispatch/slice spans.
+            from repro.sim import run as simrun
+            for name in preps:
+                design = fleet._design(name)
+                if design is not None:
+                    simrun.simulate_placement(
+                        design.placement, tenant=name,
+                        config=simrun.SimConfig(events=2), tracer=tracer)
+            tracer.save(args.trace_out)
+            print(f"[fleet] unified trace: {len(tracer.spans())} spans "
+                  f"-> {args.trace_out}")
+    finally:
+        fleet.close()
     if telemetry is not None:
         _report_telemetry(fleet, telemetry, args)
     for name, m in modeled.items():
@@ -359,16 +369,18 @@ def _serve_fleet(preps: dict, args) -> None:
             print(f"[slo] error budget exhausted for "
                   f"{report.exhausted_tenants} -> exit 1")
             raise SystemExit(report.exit_code())
+    return results
 
 
-def main() -> None:
+def main(argv: Optional[Sequence[str]] = None) -> dict:
+    """Run the driver; return ``{tenant: results}`` (see ``_serve_fleet``)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--model", choices=list(MODELS), default="deepsets-32")
     ap.add_argument("--mix", type=str, default=None,
                     help="comma-separated model names served side by side "
                          "(overrides --model)")
     ap.add_argument("--replicas", type=int, default=1,
-                    help="replica kernels per tenant (>1 => FleetServer)")
+                    help="replica kernels per tenant")
     ap.add_argument("--policy", choices=["rr", "least_loaded"],
                     default="least_loaded")
     ap.add_argument("--events", type=int, default=256)
@@ -403,7 +415,7 @@ def main() -> None:
     ap.add_argument("--admission-depth", type=int, default=None,
                     help="shed offered events when every replica queue is "
                          "at/above this depth (None = never shed)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.replicas < 1:
         ap.error("--replicas must be >= 1")
 
@@ -432,20 +444,17 @@ def main() -> None:
         except ValueError as exc:
             ap.error(str(exc))
 
+    cache = platform.enable_compile_cache()
+    dev = platform.device_info()
+    args.device = f"{dev['platform']} {dev['kind']} x{dev['count']}"
+    kernels = ("interpreted (CPU backend)" if platform.interpret()
+               else "compiled")
+    print(f"[serve] device: {args.device}; Pallas kernels {kernels}; "
+          f"compile cache {cache}")
     preps = {n: _prepare(n, train_steps=args.train_steps,
                          replicas=args.replicas, mode=args.mode)
              for n in names}
-    telemetry_requested = (args.metrics_out or args.trace_out
-                           or args.drift_gate is not None
-                           or args.arrival_spec is not None
-                           or args.slo_specs is not None
-                           or args.admission_depth is not None)
-    if len(names) == 1 and args.replicas == 1 and not telemetry_requested:
-        _serve_single(preps[names[0]], args)
-    else:
-        # The telemetry flags route through the fleet path even for one
-        # replica: the registry/tracer/drift plumbing lives there.
-        _serve_fleet(preps, args)
+    return _serve_fleet(preps, args)
 
 
 if __name__ == "__main__":

@@ -162,9 +162,10 @@ model. Two families are reported side by side and must not be conflated:
     (a negative calibration constant yields a negative share) and a
     category empty on both sides is skipped, not scored as agreement.
   * ``serve.*`` metrics compare the modeled VEK280 numbers against
-    *wall-clock CPU interpret-mode* serving, where the ratio is expected
-    to be orders of magnitude above 1 — it tracks relative drift of the
-    deployment over time, not absolute agreement.
+    *wall-clock* serving on whatever device runs it (a TPU, or a CPU
+    interpreting the kernels), where the ratio is expected to be orders
+    of magnitude above 1 — it tracks relative drift of the deployment
+    over time, not absolute agreement.
 """
 from __future__ import annotations
 

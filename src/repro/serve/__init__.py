@@ -28,6 +28,7 @@ import numpy as np
 from repro.core import tpu_model
 from repro.core.fusion_planner import FusionPlan, plan
 from repro.core.tpu_model import LayerShape
+from repro.launch import platform
 from repro.quant import QuantizedMLP, quantize_pow2
 from repro.kernels.cascade_mlp import (cascade_mlp, cascade_mlp_ref, deepsets,
                                        deepsets_ref, mlp_unfused)
@@ -84,6 +85,17 @@ class _Request:
     t_start: Optional[float] = None
     """When the serving batch holding this request began executing; the gap
     from ``t_submit`` is the queue wait (collection window + backlog)."""
+    error: Optional[Exception] = None
+    """What the model function raised for this request's batch (a compile
+    error, say); set instead of ``result`` and re-raised by the waiter."""
+
+    def wait(self, timeout: float) -> np.ndarray:
+        """Block until served; return the result or re-raise its error."""
+        if not self.event.wait(timeout):
+            raise TimeoutError("inference timed out")
+        if self.error is not None:
+            raise self.error
+        return self.result
 
     @property
     def latency_us(self) -> float:
@@ -101,6 +113,8 @@ class JetServer:
 
     ``mode``: 'fused' (single cascade kernel), 'unfused' (per-layer chain),
     'ref' (pure-jnp oracle; used in tests for bit-identical checks).
+    ``interpret`` defaults to what the platform needs
+    (:func:`repro.launch.platform.interpret`): compiled kernels on a TPU.
     """
 
     def __init__(self, qmlp: QuantizedMLP, *,
@@ -109,13 +123,14 @@ class JetServer:
                  mode: str = "fused",
                  max_batch: int = 64,
                  window_us: float = 200.0,
-                 interpret: bool = True,
+                 interpret: Optional[bool] = None,
                  on_done: Optional[Callable[[_Request], None]] = None):
         self.qmlp, self.rho, self.agg = qmlp, rho, agg
         self.mode = mode
         self.max_batch = max_batch
         self.window_us = window_us
-        self.interpret = interpret
+        self.interpret = (platform.interpret() if interpret is None
+                          else interpret)
         self.on_done = on_done
         self.stats = ServeStats()
         self._q: "queue.Queue[_Request]" = queue.Queue()
@@ -154,10 +169,7 @@ class JetServer:
         return req
 
     def infer(self, x: np.ndarray, timeout: float = 30.0) -> np.ndarray:
-        req = self.submit(x)
-        if not req.event.wait(timeout):
-            raise TimeoutError("inference timed out")
-        return req.result
+        return self.submit(x).wait(timeout)
 
     def close(self):
         self._stop.set()
@@ -189,8 +201,16 @@ class JetServer:
             t_start = time.perf_counter()
             for r in batch:
                 r.t_start = t_start
-            xs = jnp.asarray(np.stack([r.x for r in batch]))
-            out = np.asarray(self._fn(xs))
+            try:
+                xs = jnp.asarray(np.stack([r.x for r in batch]))
+                out = np.asarray(self._fn(xs))
+            except Exception as exc:
+                # A worker that died here would leave every waiter to time
+                # out; hand the error (e.g. the kernel compiler's) to each.
+                for r in batch:
+                    r.error = exc
+                    r.event.set()
+                continue
             t_done = time.perf_counter()
             for i, r in enumerate(batch):
                 r.result = out[i]

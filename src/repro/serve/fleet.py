@@ -27,8 +27,8 @@ across replicas, plus per-replica dispatch accounting) side by side with the
 *modeled* Tier-A numbers for the same replica count on the VEK280 — since
 the pipelined execution model, both the serial ``R / latency`` figures and
 the contended pipelined frontier point ({latency, II, sustained events/sec}
-from :func:`repro.core.tenancy.throughput_frontier`), so the interpret-mode
-CPU run and the analytical hardware story stay comparable.
+from :func:`repro.core.tenancy.throughput_frontier`), so the measured run
+and the analytical hardware story stay comparable.
 """
 from __future__ import annotations
 
@@ -108,7 +108,6 @@ class FleetServer:
                  policy: str = "least_loaded",
                  max_batch: int = 64,
                  window_us: float = 200.0,
-                 interpret: bool = True,
                  registry: Optional[MetricsRegistry] = None,
                  tracer: Optional[Tracer] = None,
                  slos: Optional[Dict[str, SLOSpec]] = None,
@@ -164,8 +163,7 @@ class FleetServer:
             self.tenants[t.name] = t
             servers = [
                 JetServer(t.qmlp, rho=t.rho, agg=t.agg, mode=t.mode,
-                          max_batch=max_batch, window_us=window_us,
-                          interpret=interpret)
+                          max_batch=max_batch, window_us=window_us)
                 for _ in range(t.replicas)]
             self._servers[t.name] = servers
             self._dispatched[t.name] = [0] * t.replicas
@@ -250,10 +248,7 @@ class FleetServer:
 
     def infer(self, x: np.ndarray, tenant: Optional[str] = None,
               timeout: float = 30.0) -> np.ndarray:
-        req = self.submit(x, tenant)
-        if not req.event.wait(timeout):
-            raise TimeoutError("fleet inference timed out")
-        return req.result
+        return self.submit(x, tenant).wait(timeout)
 
     def offer(self, x: np.ndarray,
               tenant: Optional[str] = None) -> Optional[_Request]:
@@ -353,13 +348,11 @@ class FleetServer:
 
     def gather(self, reqs: Sequence[_Request],
                timeout: float = 30.0) -> np.ndarray:
-        """Wait for every request and stack results in submission order."""
+        """Wait for every request and stack results in submission order;
+        re-raises the first error a replica hit while serving them."""
         if not reqs:
             return np.empty((0,))
-        for i, req in enumerate(reqs):
-            if not req.event.wait(timeout):
-                raise TimeoutError(f"batched event {i} timed out")
-        return np.stack([req.result for req in reqs])
+        return np.stack([req.wait(timeout) for req in reqs])
 
     def infer_batch(self, xs: Sequence[np.ndarray],
                     tenant: Optional[str] = None,
@@ -546,8 +539,9 @@ class FleetServer:
 
           * ``serve.latency_us`` / ``serve.interval_us`` per replica key
             ``tenant#i`` — measured wall-clock serving against the Tier-A
-            modeled VEK280 numbers. Interpret-mode CPU serving sits orders
-            of magnitude above the modeled hardware, so these ratios track
+            modeled VEK280 numbers. Serving wall clock (on a TPU, or a CPU
+            interpreting the kernels) sits orders of magnitude above the
+            modeled hardware, so these ratios track
             *relative* drift across replicas and over time, never absolute
             accuracy.
           * ``model.latency_ns`` / ``model.interval_ns`` per tenant — Tier-A

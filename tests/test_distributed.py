@@ -5,16 +5,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-# Version gate instead of a CI ignore-list entry: the sharding APIs this
-# module drives (jax.sharding.AxisType, the AbstractMesh/axis_types mesh
-# constructors in repro.launch.mesh) sit outside the requirements-dev.txt
-# jax pin. The probe re-enables the whole file automatically the moment
-# the pin is reconciled (ROADMAP open item).
-if not hasattr(jax.sharding, "AxisType"):
-    pytest.skip("jax pin lacks jax.sharding.AxisType (sharding tests need "
-                "a newer jax; reconcile the requirements-dev.txt pin)",
-                allow_module_level=True)
-
 from repro import ckpt as ckpt_lib
 from repro import optim
 from repro.configs import get_reduced

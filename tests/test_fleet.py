@@ -203,6 +203,38 @@ class TestFleetServer:
             FleetServer([TenantSpec(name="m", qmlp=q),
                          TenantSpec(name="m", qmlp=q)])
 
+    def test_model_error_reaches_caller(self, qmlp):
+        """A replica whose model function raises (say, a kernel compile
+        error) hands that exception to every waiter at once; the batch
+        raises it instead of waiting out its timeout, and the worker keeps
+        serving."""
+        q, jc = qmlp
+        fleet = FleetServer([TenantSpec(name="m", qmlp=q, mode="ref",
+                                        replicas=2)])
+        err = RuntimeError("Mosaic failed to compile TPU kernel")
+
+        def broken(xs):
+            raise err
+
+        try:
+            fns = [s._fn for s in fleet._servers["m"]]
+            for s in fleet._servers["m"]:
+                s._fn = broken
+            xs = _events(jc, 6, q.e_in)
+            t0 = time.perf_counter()
+            with pytest.raises(RuntimeError) as info:
+                fleet.infer_batch(xs, timeout=30)
+            assert info.value is err
+            with pytest.raises(RuntimeError) as info:
+                fleet.infer(xs[0], timeout=30)
+            assert info.value is err
+            assert time.perf_counter() - t0 < 10
+            for s, fn in zip(fleet._servers["m"], fns):
+                s._fn = fn
+            assert fleet.infer_batch(xs, timeout=30).n == 6
+        finally:
+            fleet.close()
+
 
 class TestFleetTelemetry:
     def test_dispatch_metrics_recorded(self, qmlp):

@@ -11,30 +11,7 @@ def _compile(fn, *avals):
     return jax.jit(fn).lower(*avals).compile()
 
 
-def _cost_analysis_is_dict() -> bool:
-    """Feature probe replacing the CI ignore-list entry: under the
-    requirements-dev.txt jax pin, Compiled.cost_analysis() returns a list
-    of dicts rather than the flat dict the cross-checks below index into.
-    Auto-re-enables once the pin is reconciled (ROADMAP open item). Any
-    probe failure means the API is unusable on this jax — skip, never
-    error collection (the failure mode the old ignore-list papered over).
-    """
-    try:
-        c = _compile(lambda x: x + 1.0,
-                     jax.ShapeDtypeStruct((2,), jnp.float32))
-        return isinstance(c.cost_analysis(), dict)
-    except Exception:
-        return False
-
-
-needs_cost_dict = pytest.mark.skipif(
-    not _cost_analysis_is_dict(),
-    reason="jax pin: Compiled.cost_analysis() returns a list, not a dict; "
-           "reconcile the requirements-dev.txt pin")
-
-
 class TestFlops:
-    @needs_cost_dict
     def test_plain_dot_matches_cost_analysis(self):
         a = jax.ShapeDtypeStruct((64, 128), jnp.float32)
         b = jax.ShapeDtypeStruct((128, 32), jnp.float32)
@@ -44,7 +21,6 @@ class TestFlops:
         assert got == pytest.approx(want, rel=1e-6)
         assert got == 2 * 64 * 128 * 32
 
-    @needs_cost_dict
     def test_scan_multiplies_by_trip_count(self):
         """cost_analysis counts a while body ONCE; the analyzer must scale
         by the known trip count (the whole point of the module)."""

@@ -6,18 +6,6 @@ import subprocess
 import sys
 import textwrap
 
-import jax
-import pytest
-
-# Version gate instead of a CI ignore-list entry: the subprocess script
-# builds its mesh via repro.launch.mesh.make_mesh, which needs
-# jax.sharding.AxisType — outside the requirements-dev.txt jax pin. The
-# probe re-enables the file automatically once the pin is reconciled.
-if not hasattr(jax.sharding, "AxisType"):
-    pytest.skip("jax pin lacks jax.sharding.AxisType (make_mesh needs a "
-                "newer jax; reconcile the requirements-dev.txt pin)",
-                allow_module_level=True)
-
 SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"

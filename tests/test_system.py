@@ -1,6 +1,9 @@
-"""End-to-end system behaviour tests: serving engine, train auto-resume,
-gradient compression, fault-tolerance watchdog."""
+"""End-to-end system behaviour tests: serving engine, device and compile
+cache helpers, train auto-resume, gradient compression, fault-tolerance
+watchdog."""
+import importlib.util
 import os
+import pathlib
 import time
 
 import jax
@@ -15,9 +18,13 @@ from repro.data import JetConfig, jet_batch
 from repro.distributed import compression
 from repro.distributed.ft import StepWatchdog, WatchdogConfig
 from repro.distributed.steps import make_train_step
+from repro.launch import platform, serve
 from repro.models import build
 from repro.models import deepsets as ds
 from repro.serve import JetServer
+
+
+REPO = pathlib.Path(__file__).resolve().parents[1]
 
 
 def _quantize_inputs(x, e_in):
@@ -62,6 +69,90 @@ class TestServing:
             assert max(srv.stats.batch_sizes) > 1, "no batching happened"
         finally:
             srv.close()
+
+    def test_model_error_raises_in_caller(self):
+        """An event the jitted model cannot take makes ``infer`` raise the
+        tracer's own error at once, not a TimeoutError after the wait."""
+        params = ds.deepsets_init(jax.random.key(2), 8, [16, 16], [16, 5])
+        x, _ = jet_batch(JetConfig(n_particles=8, n_features=8, n_classes=5),
+                         16, 3)
+        qphi, qrho = ds.to_quantized(params, x)
+        srv = JetServer(qphi, rho=qrho, mode="ref", window_us=50.0)
+        try:
+            wrong = np.zeros((8, 5), np.int8)  # 5 features, the model wants 8
+            t0 = time.perf_counter()
+            with pytest.raises(TypeError, match="dot_general"):
+                srv.infer(wrong, timeout=30)
+            assert time.perf_counter() - t0 < 10
+            xq = _quantize_inputs(x, qphi.e_in)
+            assert srv.infer(xq[0], timeout=30).shape == (1, 5)
+        finally:
+            srv.close()
+
+
+@pytest.fixture
+def jax_cache_config():
+    """Restore JAX's persistent-cache settings after a test changes them."""
+    from jax.experimental.compilation_cache import compilation_cache
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    saved = {n: getattr(jax.config, n) for n in names}
+    yield
+    for n, v in saved.items():
+        jax.config.update(n, v)
+    compilation_cache.reset_cache()
+
+
+class TestPlatform:
+    def test_cpu_backend_interprets(self):
+        assert os.environ.get("JAX_PLATFORMS") == "cpu"
+        assert platform.interpret() is True
+        info = platform.device_info()
+        assert info["platform"] == "cpu" and info["count"] >= 1
+        params = ds.deepsets_init(jax.random.key(3), 8, [16], [5])
+        x, _ = jet_batch(JetConfig(n_particles=8, n_features=8, n_classes=5),
+                         4, 4)
+        qphi, qrho = ds.to_quantized(params, x)
+        srv = JetServer(qphi, rho=qrho)
+        srv.close()
+        assert srv.interpret is True
+
+    def test_compile_cache_honours_env(self, monkeypatch, tmp_path,
+                                       jax_cache_config):
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        before = jax.config.jax_compilation_cache_dir
+        assert platform.enable_compile_cache() == str(tmp_path)
+        # JAX reads the variable itself; the helper sets no other directory
+        assert jax.config.jax_compilation_cache_dir == before
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+        assert jax.config.jax_persistent_cache_min_entry_size_bytes == 0
+
+    def test_compile_cache_default_is_fixed_in_checkout(self, monkeypatch,
+                                                        jax_cache_config):
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        first = platform.enable_compile_cache()
+        assert platform.enable_compile_cache() == first
+        assert first == str(REPO / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == first
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+
+    def test_chip_smoke_body_matches_reference(self, monkeypatch, tmp_path,
+                                               jax_cache_config):
+        """The smoke's body at a few events, interpreted on the CPU: every
+        served output equals the jnp reference."""
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        spec = importlib.util.spec_from_file_location(
+            "chip_smoke", REPO / "chip_smoke.py")
+        smoke = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(smoke)
+        reports = smoke.run(events=4, train_steps=1)
+        assert [r["model"] for r in reports] == list(smoke.MODELS)
+        for r in reports:
+            assert r["failures"] == []
+            assert r["events"] == 4 + serve.SINGLE_EVENTS
+            assert r["exact"] == r["events"]
+            assert r["tpu_custom_call"] is False
 
 
 class TestTrainResume:
@@ -115,10 +206,6 @@ class TestGradientCompression:
         resid = float(jnp.max(jnp.abs(acc - total)))
         assert resid <= float(s) + 1e-6
 
-    @pytest.mark.skipif(
-        not hasattr(jax.sharding, "AxisType"),
-        reason="jax pin lacks jax.sharding.AxisType / make_mesh axis_types; "
-               "reconcile the requirements-dev.txt pin")
     def test_compressed_psum_single_axis(self):
         mesh = jax.make_mesh((1,), ("pod",),
                              axis_types=(jax.sharding.AxisType.Auto,))
