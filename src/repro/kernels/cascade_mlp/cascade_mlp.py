@@ -110,6 +110,7 @@ def cascade_mlp_pallas(x: jax.Array, qmlp: QuantizedMLP, *,
         out_specs=pl.BlockSpec((block_m, n_out), lambda i: (i, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((M, n_out), jnp.int8),
+        name="cascade_mlp",
         interpret=interpret,
     )(*args)
 
@@ -182,5 +183,6 @@ def deepsets_pallas(x: jax.Array, phi: QuantizedMLP, rho: QuantizedMLP, *,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, n_out), memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((1, n_out), jnp.int8),
+        name="deepsets",
         interpret=interpret,
     )(x, *phi_args, *rho_args)

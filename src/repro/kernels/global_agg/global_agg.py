@@ -79,5 +79,6 @@ def global_agg_pallas(x: jax.Array, *, op: str = "sum",
         out_specs=pl.BlockSpec((1, block_f), lambda j: (0, j),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((1, F), out_dtype),
+        name="global_agg",
         interpret=interpret,
     )(x)

@@ -101,5 +101,6 @@ def mm_int8_pallas(x: jax.Array, w: jax.Array,
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j: (i, j),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
+        name="mm_int8",
         interpret=interpret,
     )(*args)

@@ -286,14 +286,14 @@ def _serve_fleet(preps: dict, args) -> dict:
     """
     tracer = None
     if args.trace_out:
-        # A ChromeTrace carries both clocks: fleet spans are wall-clock
-        # (span_us), simulator spans are AIE cycles (span) — one timeline.
+        # Cycle-clock lanes of a short Tier-S run per tenant; the served
+        # path's spans are in the profiler's trace.
         from repro.sim.trace import ChromeTrace
         tracer = ChromeTrace(meta={"driver": "serve",
                                    "mix": ",".join(preps),
                                    "policy": args.policy})
     fleet = FleetServer([p["tenant"] for p in preps.values()],
-                        policy=args.policy, tracer=tracer,
+                        policy=args.policy,
                         slos=args.slo_specs,
                         admission_depth=args.admission_depth)
     print(f"\n[fleet] {fleet.num_replicas} replicas across "
@@ -318,8 +318,6 @@ def _serve_fleet(preps: dict, args) -> dict:
                      if (args.metrics_out or args.trace_out
                          or args.drift_gate is not None) else None)
         if tracer is not None:
-            # Append a short Tier-S run per tenant so simulator task spans
-            # land in the same trace as the fleet's dispatch/slice spans.
             from repro.sim import run as simrun
             for name in preps:
                 design = fleet._design(name)
@@ -328,7 +326,7 @@ def _serve_fleet(preps: dict, args) -> dict:
                         design.placement, tenant=name,
                         config=simrun.SimConfig(events=2), tracer=tracer)
             tracer.save(args.trace_out)
-            print(f"[fleet] unified trace: {len(tracer.spans())} spans "
+            print(f"[fleet] Tier-S trace: {len(tracer.spans())} spans "
                   f"-> {args.trace_out}")
     finally:
         fleet.close()
@@ -391,8 +389,10 @@ def main(argv: Optional[Sequence[str]] = None) -> dict:
                          "(queue depths, dispatch overheads, rolling "
                          "percentiles, drift ratios) as JSON")
     ap.add_argument("--trace-out", type=str, default=None,
-                    help="write a unified Chrome trace: fleet dispatch/slice "
-                         "spans + a short Tier-S sim per tenant")
+                    help="write a Chrome trace of a short Tier-S sim per "
+                         "tenant; the served path's spans (fleet.*, "
+                         "serve.*) are in the profiler trace "
+                         "(jax.profiler.start_trace)")
     ap.add_argument("--drift-gate", type=float, default=None,
                     help="fail (exit 1) when the Tier-A-vs-Tier-S model-path "
                          "drift MAPE exceeds this fraction (e.g. 0.05)")

@@ -13,10 +13,14 @@ Three pieces:
     gauges, and streaming histograms (fixed log buckets + P² quantile
     estimators), labelled, mergeable across replicas, exported as a JSON
     snapshot or Prometheus text.
-  * :class:`Tracer` (:mod:`repro.obs.tracing`) — Chrome-trace span
-    recording with stable pid/tid lane conventions. The simulator's
-    :class:`repro.sim.trace.ChromeTrace` is a cycle-clock subclass, so
-    simulator task spans and fleet wall-clock spans land in one timeline.
+  * :func:`span` and :class:`Tracer` (:mod:`repro.obs.tracing`) — spans.
+    :func:`span` is the served path's: a ``jax.profiler.TraceAnnotation``
+    on the profiler's clock, in the same trace as the device's operations,
+    recorded only while a profiler session runs (``fleet.submit``,
+    ``serve.step`` and its parts; the table is in that module).
+    :class:`Tracer` is Chrome-trace JSON with stable pid/tid lanes for the
+    modeled layers: the simulator's :class:`repro.sim.trace.ChromeTrace`
+    is its cycle-clock subclass, and the DSE's phases are wall-clock spans.
   * :class:`DriftMonitor` (:mod:`repro.obs.drift`) — modeled-vs-measured
     comparison: register the model's expectation per key, stream in
     measurements, read back per-key drift ratios and a fig9-style MAPE.
@@ -176,11 +180,11 @@ from .profile import (BlameSegment, EventProfile, RunProfile,
                       is_wait_category, profile_run, top_levers, whatif)
 from .slo import (BurnAlert, BurnWindow, SLOReport, SLOSpec, SLOTracker,
                   parse_slo)
-from .tracing import DEFAULT_PIDS, Tracer
+from .tracing import DEFAULT_PIDS, Tracer, span
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "P2Quantile",
-    "Tracer", "DEFAULT_PIDS", "DriftMonitor", "DriftEntry",
+    "Tracer", "DEFAULT_PIDS", "span", "DriftMonitor", "DriftEntry",
     "SLOSpec", "SLOTracker", "SLOReport", "BurnWindow", "BurnAlert",
     "parse_slo",
     "BlameSegment", "EventProfile", "RunProfile", "WhatIfProjection",

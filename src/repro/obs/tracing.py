@@ -1,9 +1,18 @@
-"""Chrome-trace span recording with stable pid/tid lane conventions.
+"""Span recording: the served path on the profiler's clock, the modeled
+layers in Chrome-trace JSON.
 
-One :class:`Tracer` accumulates complete ("ph": "X") spans from every
-subsystem into a single ``chrome://tracing`` / Perfetto timeline. Lane
-conventions (trace *processes*) are fixed so simulator and fleet spans
-group predictably side by side:
+Two clocks, two recorders:
+
+  * :func:`span` — the runtime. Each span is a
+    ``jax.profiler.TraceAnnotation``: it records nothing unless a profiler
+    session (``jax.profiler.start_trace``) is running, and then lands on the
+    calling thread's line in the same trace as the device's operations, so
+    a device idle gap can be read against what the host was doing. The
+    served path (``repro.serve``) writes spans through this function alone.
+  * :class:`Tracer` — complete ("ph": "X") spans accumulated into one
+    ``chrome://tracing`` / Perfetto timeline, for the simulator's cycle
+    clock and the DSE's wall-clock phases. Lane conventions (trace
+    *processes*) are fixed so the lanes group predictably side by side:
 
   ============  ===================================================
   pid lane      rows (tids)
@@ -13,15 +22,38 @@ group predictably side by side:
   ``fifo``      cascade / shared-memory FIFOs (sim)
   ``dma``       DMA routes (sim)
   ``shim``      one per shim column — PLIO transfers (sim)
-  ``fleet``     one per serving replica + a ``dispatch`` row (runtime)
-  ``dse``       one per model — search phase spans
+  ``dse``       one per model — search phase spans (wall clock)
   ============  ===================================================
 
 Timestamps are microseconds (the Chrome-trace unit). Simulated spans are
 converted from AIE cycles by :class:`repro.sim.trace.ChromeTrace` (a
-subclass of this Tracer); runtime spans use the tracer's wall clock
+subclass of this Tracer); wall-clock spans use the tracer's own clock
 (:meth:`Tracer.now_us` / :meth:`Tracer.region`), anchored at tracer
 construction so a run starts near t=0.
+
+Served-path spans (:func:`span`), by thread:
+
+  ==========================  =========================================
+  span                        covers
+  ==========================  =========================================
+  ``fleet.submit``            the caller's thread: replica pick, enqueue
+                              and dispatch telemetry (arg ``replica``)
+  ``fleet.infer_batch``       the caller's thread: scatter and gather of
+                              one micro-batch (args ``events``,
+                              ``replica_counts``)
+  ``serve.wait``              a worker: blocked on the queue for the
+                              first event of a batch
+  ``serve.collect``           a worker: first event in hand to the batch
+                              closed (the collection window)
+  ``serve.step``              a worker: ``t_start`` to the last answer
+                              handed back (args ``step``, ``size``,
+                              ``new_shape``); holds, in order,
+  ``serve.to_device``         ``np.stack`` and the host-to-device copy
+  ``serve.dispatch``          the jitted call, until it returns
+  ``serve.to_host``           waiting for the kernel and copying back
+  ``serve.reply``             answers set, completion observers run,
+                              waiters woken
+  ==========================  =========================================
 """
 from __future__ import annotations
 
@@ -33,7 +65,27 @@ from typing import Dict, List, Optional
 #: Stable pid numbering so lanes group predictably in the viewer. New pid
 #: names allocate increasing ids per tracer instance.
 DEFAULT_PIDS = {"events": 1, "tiles": 2, "fifo": 3, "dma": 4, "shim": 5,
-                "fleet": 6, "dse": 7}
+                "dse": 7}
+
+
+#: ``jax.profiler.TraceAnnotation`` once :func:`span` has first run.
+_annotation = None
+
+
+def span(name: str, **args):
+    """A span of the calling thread on the profiler's clock.
+
+    A ``jax.profiler.TraceAnnotation``: a context manager that records
+    ``name`` with ``args`` while a profiler session runs, and records
+    nothing otherwise (about half a microsecond on a TPU v5e host). JAX is
+    imported only here, so this module loads without it. Arguments known
+    only inside the block go in through the context's ``set_metadata``.
+    """
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    return _annotation(name, **args)
 
 
 class Tracer:

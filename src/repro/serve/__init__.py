@@ -29,6 +29,7 @@ from repro.core import tpu_model
 from repro.core.fusion_planner import FusionPlan, plan
 from repro.core.tpu_model import LayerShape
 from repro.launch import platform
+from repro.obs import span
 from repro.quant import QuantizedMLP, quantize_pow2
 from repro.kernels.cascade_mlp import (cascade_mlp, cascade_mlp_ref, deepsets,
                                        deepsets_ref, mlp_unfused)
@@ -64,8 +65,8 @@ class ServeStats:
         """Measured events/sec over the first-submit .. last-done window."""
         if self.t_first_submit is None or self.t_last_done is None:
             return 0.0
-        span = self.t_last_done - self.t_first_submit
-        return len(self.latencies_us) / span if span > 0 else 0.0
+        window_s = self.t_last_done - self.t_first_submit
+        return len(self.latencies_us) / window_s if window_s > 0 else 0.0
 
     def summary(self) -> dict:
         return {"n": len(self.latencies_us),
@@ -134,6 +135,9 @@ class JetServer:
         self.on_done = on_done
         self.stats = ServeStats()
         self._q: "queue.Queue[_Request]" = queue.Queue()
+        self._step = 0
+        #: Batch sizes served so far: a new one compiles or loads a program.
+        self._shapes: set = set()
         self._stop = threading.Event()
         self._fn = self._build()
         self._thread = threading.Thread(target=self._loop, daemon=True)
@@ -177,20 +181,22 @@ class JetServer:
 
     # -- batching loop ----------------------------------------------------------
     def _collect(self) -> List[_Request]:
-        try:
-            first = self._q.get(timeout=0.05)
-        except queue.Empty:
-            return []
-        batch = [first]
-        deadline = time.perf_counter() + self.window_us * 1e-6
-        while len(batch) < self.max_batch:
-            remaining = deadline - time.perf_counter()
-            if remaining <= 0:
-                break
+        with span("serve.wait"):
             try:
-                batch.append(self._q.get(timeout=remaining))
+                first = self._q.get(timeout=0.05)
             except queue.Empty:
-                break
+                return []
+        with span("serve.collect"):
+            batch = [first]
+            deadline = time.perf_counter() + self.window_us * 1e-6
+            while len(batch) < self.max_batch:
+                remaining = deadline - time.perf_counter()
+                if remaining <= 0:
+                    break
+                try:
+                    batch.append(self._q.get(timeout=remaining))
+                except queue.Empty:
+                    break
         return batch
 
     def _loop(self):
@@ -199,32 +205,47 @@ class JetServer:
             if not batch:
                 continue
             t_start = time.perf_counter()
-            for r in batch:
-                r.t_start = t_start
-            try:
-                xs = jnp.asarray(np.stack([r.x for r in batch]))
-                out = np.asarray(self._fn(xs))
-            except Exception as exc:
-                # A worker that died here would leave every waiter to time
-                # out; hand the error (e.g. the kernel compiler's) to each.
+            self._step += 1
+            size = len(batch)
+            with span("serve.step", step=self._step, size=size,
+                          new_shape=int(size not in self._shapes)):
                 for r in batch:
-                    r.error = exc
-                    r.event.set()
-                continue
-            t_done = time.perf_counter()
-            for i, r in enumerate(batch):
-                r.result = out[i]
-                r.t_done = t_done
-                self.stats.record(r.t_submit, t_done)
-                if self.on_done is not None:
-                    # Telemetry must never wedge the worker loop: a raising
-                    # observer would strand every waiter on this queue.
-                    try:
-                        self.on_done(r)
-                    except Exception:
-                        pass
-                r.event.set()
-            self.stats.batch_sizes.append(len(batch))
+                    r.t_start = t_start
+                try:
+                    with span("serve.to_device"):
+                        xs = jnp.asarray(np.stack([r.x for r in batch]))
+                    with span("serve.dispatch"):
+                        ys = self._fn(xs)
+                    with span("serve.to_host"):
+                        out = np.asarray(ys)
+                except Exception as exc:
+                    # A worker that died here would leave every waiter to
+                    # time out; hand the error (e.g. the kernel compiler's)
+                    # to each.
+                    for r in batch:
+                        r.error = exc
+                        r.event.set()
+                    continue
+                t_done = time.perf_counter()
+                self._shapes.add(size)
+                with span("serve.reply"):
+                    self._reply(batch, out, t_done)
+
+    def _reply(self, batch: List[_Request], out: np.ndarray,
+               t_done: float) -> None:
+        for i, r in enumerate(batch):
+            r.result = out[i]
+            r.t_done = t_done
+            self.stats.record(r.t_submit, t_done)
+            if self.on_done is not None:
+                # Telemetry must never wedge the worker loop: a raising
+                # observer would strand every waiter on this queue.
+                try:
+                    self.on_done(r)
+                except Exception:
+                    pass
+            r.event.set()
+        self.stats.batch_sizes.append(len(batch))
 
     # -- Tier-B modeled latency on the TPU target --------------------------------
     def modeled_latency_us(self) -> dict:

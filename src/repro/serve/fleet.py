@@ -40,7 +40,7 @@ import numpy as np
 
 from repro.core import aie_arch, dse, tenancy
 from repro.core.layerspec import ModelSpec
-from repro.obs import DriftMonitor, MetricsRegistry, Tracer
+from repro.obs import DriftMonitor, MetricsRegistry, span
 from repro.obs.slo import SLOReport, SLOSpec, SLOTracker
 from repro.quant import QuantizedMLP
 from repro.serve import JetServer, ServeStats, _Request
@@ -109,7 +109,6 @@ class FleetServer:
                  max_batch: int = 64,
                  window_us: float = 200.0,
                  registry: Optional[MetricsRegistry] = None,
-                 tracer: Optional[Tracer] = None,
                  slos: Optional[Dict[str, SLOSpec]] = None,
                  admission_depth: Optional[int] = None):
         if policy not in ("rr", "least_loaded"):
@@ -118,7 +117,6 @@ class FleetServer:
             raise ValueError("at least one tenant required")
         self.policy = policy
         self.registry = registry if registry is not None else MetricsRegistry()
-        self.tracer = tracer
         self.drift = DriftMonitor()
         #: offered events above this per-replica queue depth are shed by
         #: :meth:`offer` (None = admit everything, the pre-SLO behavior)
@@ -237,13 +235,16 @@ class FleetServer:
         name = tenant or self._default
         if name not in self._servers:
             raise KeyError(f"unknown tenant {name!r}")
-        t0 = time.perf_counter()
-        i = self._pick(name)
-        self._dispatched[name][i] += 1
-        self._m_dispatched[name][i].inc()
-        req = self._servers[name][i].submit(x)
-        self._m_depth[name][i].set(float(self._servers[name][i]._q.qsize()))
-        self._m_overhead[name].record((time.perf_counter() - t0) * 1e6)
+        with span("fleet.submit") as sp:
+            t0 = time.perf_counter()
+            i = self._pick(name)
+            sp.set_metadata(replica=i)
+            self._dispatched[name][i] += 1
+            self._m_dispatched[name][i].inc()
+            req = self._servers[name][i].submit(x)
+            self._m_depth[name][i].set(
+                float(self._servers[name][i]._q.qsize()))
+            self._m_overhead[name].record((time.perf_counter() - t0) * 1e6)
         return req
 
     def infer(self, x: np.ndarray, tenant: Optional[str] = None,
@@ -365,10 +366,14 @@ class FleetServer:
             return BatchResult(results=np.empty((0,)), stats=ServeStats(),
                                wall_us=0.0,
                                replica_counts=[0] * len(self._servers[name]))
-        t0 = time.perf_counter()
-        reqs, counts = self._submit_batch(xs, name)
-        results = self.gather(reqs, timeout=timeout)
-        t1 = time.perf_counter()
+        with span("fleet.infer_batch", events=len(xs)) as sp:
+            t0 = time.perf_counter()
+            reqs, counts = self._submit_batch(xs, name)
+            # Events per replica, "/"-joined: "," and "=" delimit the
+            # profiler's own encoding of span arguments.
+            sp.set_metadata(replica_counts="/".join(map(str, counts)))
+            results = self.gather(reqs, timeout=timeout)
+            t1 = time.perf_counter()
         wall_us = (t1 - t0) * 1e6
         stats = ServeStats()
         for req in reqs:
@@ -376,24 +381,6 @@ class FleetServer:
         self._m_batch[name].record(float(len(xs)))
         if wall_us > 0:
             self._m_tput[name].set(len(xs) / (wall_us * 1e-6))
-        if self.tracer is not None:
-            self.tracer.span_us(
-                "fleet", f"{name}.dispatch", f"infer_batch[{len(xs)}]",
-                self.tracer.wall_us(t0), wall_us, cat="fleet",
-                args={"replica_counts": counts})
-            start = 0
-            for i, c in enumerate(counts):
-                sl = reqs[start:start + c]
-                start += c
-                if not sl:
-                    continue
-                ts = min(r.t_submit for r in sl)
-                te = max(r.t_done for r in sl)
-                self.tracer.span_us(
-                    "fleet", f"{name}#{i}", f"slice[{c}]",
-                    self.tracer.wall_us(ts),
-                    max((te - ts) * 1e6, 0.0), cat="slice",
-                    args={"events": c})
         return BatchResult(results=results, stats=stats, wall_us=wall_us,
                            replica_counts=counts)
 

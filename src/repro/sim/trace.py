@@ -9,7 +9,7 @@ class — tiles, cascade/shared-memory FIFOs, DMA routes, shim columns — and
 one "events" process with a row per tenant instance showing whole-event
 spans. Because the base class also records wall-clock spans
 (:meth:`~repro.obs.tracing.Tracer.region`), one ChromeTrace can carry
-simulator task spans and fleet serving spans in a single timeline.
+simulator task spans and the DSE's search phases in a single timeline.
 """
 from __future__ import annotations
 

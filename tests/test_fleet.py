@@ -287,22 +287,6 @@ class TestFleetTelemetry:
         finally:
             fleet.close()
 
-    def test_batch_spans_in_tracer(self, qmlp):
-        from repro.obs import Tracer
-        q, jc = qmlp
-        tr = Tracer()
-        fleet = FleetServer([TenantSpec(name="m", qmlp=q, mode="ref",
-                                        replicas=2)], tracer=tr)
-        try:
-            fleet.infer_batch(_events(jc, 6, q.e_in))
-        finally:
-            fleet.close()
-        spans = tr.spans("fleet")
-        names = {e["name"] for e in spans}
-        assert "infer_batch[6]" in names
-        assert any(n.startswith("slice[") for n in names)
-        assert all(e["ts"] >= 0 and e["dur"] >= 0 for e in spans)
-
     def test_drift_snapshot_and_telemetry(self, qmlp):
         import json as _json
 

@@ -152,14 +152,15 @@ class TestRegistry:
 class TestTracer:
     def test_lanes_and_metadata(self):
         tr = Tracer()
-        tr.span_us("fleet", "r0", "batch", 0.0, 5.0)
-        tr.span_us("fleet", "r1", "batch", 1.0, 5.0)
+        tr.span_us("tiles", "t0", "task", 0.0, 5.0)
+        tr.span_us("tiles", "t1", "task", 1.0, 5.0)
         tr.span_us("dse", "m", "dp", 0.0, 2.0)
-        assert tr.pid("fleet") == DEFAULT_PIDS["fleet"]
+        assert tr.pid("tiles") == DEFAULT_PIDS["tiles"]
+        assert tr.pid("dse") == DEFAULT_PIDS["dse"]
         names = [e["args"]["name"] for e in tr.events
                  if e["ph"] == "M" and e["name"] == "process_name"]
-        assert names == ["fleet", "dse"]
-        assert len(tr.spans("fleet")) == 2
+        assert names == ["tiles", "dse"]
+        assert len(tr.spans("tiles")) == 2
         assert len(tr.spans()) == 3
 
     def test_new_pid_allocates_beyond_defaults(self):
@@ -169,10 +170,10 @@ class TestTracer:
 
     def test_region_nesting(self):
         tr = Tracer()
-        with tr.region("fleet", "dispatch", "outer"):
-            with tr.region("fleet", "dispatch", "inner"):
+        with tr.region("dse", "m", "outer"):
+            with tr.region("dse", "m", "inner"):
                 time.sleep(0.001)
-        spans = {e["name"]: e for e in tr.spans("fleet")}
+        spans = {e["name"]: e for e in tr.spans("dse")}
         o, i = spans["outer"], spans["inner"]
         assert o["ts"] <= i["ts"]
         assert o["ts"] + o["dur"] >= i["ts"] + i["dur"]
@@ -271,7 +272,7 @@ class TestSimTelemetry:
 
     def test_unified_timeline_sim_plus_wall(self):
         """One ChromeTrace carries cycle-clock sim spans AND wall-clock
-        fleet-style spans."""
+        DSE phase spans."""
         from repro.core import dse, layerspec
         from repro.sim import run as simrun
         from repro.sim.trace import ChromeTrace
@@ -280,11 +281,11 @@ class TestSimTelemetry:
         simrun.simulate_placement(design.placement, tenant="jsc-m",
                                   config=simrun.SimConfig(events=1),
                                   tracer=tr)
-        with tr.region("fleet", "dispatch", "batch"):
+        with tr.region("dse", "jsc-m", "score"):
             pass
         lanes = {e["args"]["name"] for e in tr.events
                  if e["ph"] == "M" and e["name"] == "process_name"}
-        assert "tiles" in lanes and "fleet" in lanes
+        assert "tiles" in lanes and "dse" in lanes
         assert all(e["ts"] >= 0 and e["dur"] >= 0 for e in tr.spans())
 
 

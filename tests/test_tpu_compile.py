@@ -110,3 +110,33 @@ def test_mlp_unfused_compiles(compile_text, batch):
 def test_global_agg_compiles(compile_text, op, impl, m, f):
     text = compile_text(lambda x: global_agg(x, op=op, impl=impl), (m, f))
     assert "tpu_custom_call" in text
+
+
+def _served(name):
+    """The served function and input shape for each named kernel."""
+    if name == "cascade_mlp":
+        q = _qmlp(MLPS["jsc-m"])
+        return (jax.vmap(lambda x: cascade_mlp(x, q)),
+                (64, PARTICLES, MLPS["jsc-m"][0]))
+    if name == "deepsets":
+        m, phi_sizes, rho_sizes = DEEPSETS["deepsets-32"]
+        phi, rho = _qmlp(phi_sizes, relu_last=True), _qmlp(rho_sizes, seed=1)
+        return (jax.vmap(lambda x: deepsets(x, phi, rho, agg="mean")),
+                (64, m, phi_sizes[0]))
+    if name == "mm_int8":
+        q = _qmlp(MLPS["jsc-m"])
+        return (jax.vmap(lambda x: mlp_unfused(x, q)),
+                (64, PARTICLES, MLPS["jsc-m"][0]))
+    return (lambda x: global_agg(x, op="mean", impl="mac"), (32, 32))
+
+
+@pytest.mark.parametrize("name", ["cascade_mlp", "deepsets", "mm_int8",
+                                  "global_agg"])
+def test_kernel_is_named_in_the_compiled_program(compile_text, name):
+    """The profiler names a device operation by its HLO instruction: the
+    kernel's ``pallas_call`` name has to reach the custom call's."""
+    fn, shape = _served(name)
+    text = compile_text(fn, shape)
+    calls = [ln.split(" = ")[0].strip() for ln in text.splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in ln]
+    assert calls and all(name in c for c in calls), calls
