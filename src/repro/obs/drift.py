@@ -11,7 +11,7 @@ against the modeled hardware numbers).
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 
 @dataclasses.dataclass
@@ -25,10 +25,15 @@ class DriftEntry:
     total: float = 0.0
     last: Optional[float] = None
 
-    def observe(self, value: float) -> None:
-        self.count += 1
-        self.total += float(value)
-        self.last = float(value)
+    def observe_many(self, values: Sequence[float]) -> None:
+        """Stream ``values`` in, in order (the total is accumulated in the
+        same order as one value at a time)."""
+        total = self.total
+        for v in values:
+            total += float(v)
+        self.count += len(values)
+        self.total = total
+        self.last = float(values[-1])
 
     @property
     def measured(self) -> Optional[float]:
@@ -71,7 +76,13 @@ class DriftMonitor:
 
     def observe(self, key: str, metric: str, value: float) -> None:
         """Stream one measurement in (mean is compared against the model)."""
-        self._entry(key, metric).observe(value)
+        self.observe_many(key, metric, (value,))
+
+    def observe_many(self, key: str, metric: str,
+                     values: Sequence[float]) -> None:
+        """Stream measurements in, in order, with one entry lookup."""
+        if values:
+            self._entry(key, metric).observe_many(values)
 
     # -- queries ---------------------------------------------------------------
     def entries(self, metric: Optional[str] = None) -> List[DriftEntry]:

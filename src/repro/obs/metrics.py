@@ -9,8 +9,8 @@ mergeable across replicas) plus P² quantile estimators (Jain & Chlamtac
 """
 from __future__ import annotations
 
+import bisect
 import json
-import math
 import re
 import threading
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
@@ -28,6 +28,22 @@ def default_buckets() -> Tuple[float, ...]:
     """1-2-5 log series from 1e-3 to 5e9 — wide enough for ns latencies,
     us wall clocks, and events/sec without per-metric tuning."""
     return tuple(c * 10.0 ** e for e in range(-3, 10) for c in (1, 2, 5))
+
+
+def _p2_move(d: float, qa: float, qi: float, qb: float,
+             na: float, ni: float, nb: float) -> float:
+    """New height of the P² marker at position ``ni``, height ``qi``, moved
+    one position in direction ``d`` (+1.0 or -1.0) between its neighbours
+    ``(na, qa)`` below and ``(nb, qb)`` above: the parabolic prediction,
+    or the linear one where the parabola would leave the markers' order."""
+    qp = qi + d / (nb - na) * (
+        (ni - na + d) * (qb - qi) / (nb - ni)
+        + (nb - ni - d) * (qi - qa) / (ni - na))
+    if qa < qp < qb:
+        return qp
+    if d > 0.0:
+        return qi + d * (qb - qi) / (nb - ni)
+    return qi + d * (qa - qi) / (na - ni)
 
 
 class P2Quantile:
@@ -52,39 +68,78 @@ class P2Quantile:
         self._dn = [0.0, p / 2.0, p, (1.0 + p) / 2.0, 1.0]
 
     def observe(self, x: float) -> None:
-        if len(self._buf) < 5 and not self._q:
-            self._buf.append(x)
-            if len(self._buf) == 5:
-                self._q = sorted(self._buf)
+        self.observe_many((x,))
+
+    def observe_many(self, xs: Sequence[float]) -> None:
+        """Stream ``xs`` in, in order; the state equals one :meth:`observe`
+        per value, bit for bit.
+
+        The markers live in locals for the whole sequence. Marker 0's
+        desired position never moves (its increment is 0) and marker 0's
+        position is always 1, so neither is updated.
+        """
+        start = 0
+        if not self._q:
+            start = 5 - len(self._buf)
+            self._buf.extend(xs[:start])
+            if len(self._buf) < 5:
+                return
+            self._q = sorted(self._buf)
+        if start >= len(xs):
             return
-        q, n = self._q, self._n
-        if x < q[0]:
-            q[0] = x
-            k = 0
-        elif x >= q[4]:
-            q[4] = x
-            k = 3
-        else:
-            k = next(i for i in range(4) if q[i] <= x < q[i + 1])
-        for i in range(k + 1, 5):
-            n[i] += 1.0
-        for i in range(5):
-            self._np[i] += self._dn[i]
-        for i in (1, 2, 3):
-            d = self._np[i] - n[i]
-            if ((d >= 1.0 and n[i + 1] - n[i] > 1.0)
-                    or (d <= -1.0 and n[i - 1] - n[i] < -1.0)):
-                d = math.copysign(1.0, d)
-                qp = q[i] + d / (n[i + 1] - n[i - 1]) * (
-                    (n[i] - n[i - 1] + d) * (q[i + 1] - q[i])
-                    / (n[i + 1] - n[i])
-                    + (n[i + 1] - n[i] - d) * (q[i] - q[i - 1])
-                    / (n[i] - n[i - 1]))
-                if not q[i - 1] < qp < q[i + 1]:   # parabolic left the order
-                    j = i + int(d)
-                    qp = q[i] + d * (q[j] - q[i]) / (n[j] - n[i])
-                q[i] = qp
-                n[i] += d
+        q0, q1, q2, q3, q4 = self._q
+        n0, n1, n2, n3, n4 = self._n
+        p0, p1, p2, p3, p4 = self._np
+        _, d1, d2, d3, d4 = self._dn
+        for i in range(start, len(xs)):
+            x = xs[i]
+            # the cell q[k] <= x < q[k + 1]: positions above it move up
+            if x < q0:
+                q0 = x
+                n1 += 1.0
+                n2 += 1.0
+                n3 += 1.0
+            elif x >= q4:
+                q4 = x
+            elif x < q1:
+                n1 += 1.0
+                n2 += 1.0
+                n3 += 1.0
+            elif x < q2:
+                n2 += 1.0
+                n3 += 1.0
+            elif x < q3:
+                n3 += 1.0
+            n4 += 1.0
+            p1 += d1
+            p2 += d2
+            p3 += d3
+            p4 += d4
+            # markers 1..3 in order, each against its updated neighbours
+            d = p1 - n1
+            if d >= 1.0 and n2 - n1 > 1.0:
+                q1 = _p2_move(1.0, q0, q1, q2, n0, n1, n2)
+                n1 += 1.0
+            elif d <= -1.0 and n0 - n1 < -1.0:
+                q1 = _p2_move(-1.0, q0, q1, q2, n0, n1, n2)
+                n1 -= 1.0
+            d = p2 - n2
+            if d >= 1.0 and n3 - n2 > 1.0:
+                q2 = _p2_move(1.0, q1, q2, q3, n1, n2, n3)
+                n2 += 1.0
+            elif d <= -1.0 and n1 - n2 < -1.0:
+                q2 = _p2_move(-1.0, q1, q2, q3, n1, n2, n3)
+                n2 -= 1.0
+            d = p3 - n3
+            if d >= 1.0 and n4 - n3 > 1.0:
+                q3 = _p2_move(1.0, q2, q3, q4, n2, n3, n4)
+                n3 += 1.0
+            elif d <= -1.0 and n2 - n3 < -1.0:
+                q3 = _p2_move(-1.0, q2, q3, q4, n2, n3, n4)
+                n3 -= 1.0
+        self._q = [q0, q1, q2, q3, q4]
+        self._n = [n0, n1, n2, n3, n4]
+        self._np = [p0, p1, p2, p3, p4]
 
     @property
     def value(self) -> float:
@@ -199,27 +254,31 @@ class Histogram(Metric):
             q: P2Quantile(q) for q in quantiles}
 
     def record(self, x: float) -> None:
-        x = float(x)
+        self.record_many((x,))
+
+    def record_many(self, xs: Iterable[float]) -> None:
+        """Record ``xs`` in order under one lock hold; the state equals one
+        :meth:`record` per value (the sum is accumulated in the same
+        order)."""
+        xs = [float(x) for x in xs]
+        if not xs:
+            return
         with self._lock:
-            self.count += 1
-            self.sum += x
-            self.min = x if self.min is None else min(self.min, x)
-            self.max = x if self.max is None else max(self.max, x)
-            i = self._bucket_index(x)
-            self.bucket_counts[i] += 1
+            self.count += len(xs)
+            total = self.sum
+            for x in xs:
+                total += x
+            self.sum = total
+            lo, hi = min(xs), max(xs)
+            self.min = lo if self.min is None else min(self.min, lo)
+            self.max = hi if self.max is None else max(self.max, hi)
+            counts, bounds = self.bucket_counts, self.bounds
+            for x in xs:
+                # first bound with x <= bound; past the last: +Inf overflow
+                counts[bisect.bisect_left(bounds, x)] += 1
             if self._p2 is not None:
                 for est in self._p2.values():
-                    est.observe(x)
-
-    def _bucket_index(self, x: float) -> int:
-        lo, hi = 0, len(self.bounds)
-        while lo < hi:
-            mid = (lo + hi) // 2
-            if x <= self.bounds[mid]:
-                hi = mid
-            else:
-                lo = mid + 1
-        return lo
+                    est.observe_many(xs)
 
     @property
     def mean(self) -> float:

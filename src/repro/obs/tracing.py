@@ -51,8 +51,9 @@ Served-path spans (:func:`span`), by thread:
   ``serve.to_device``         ``np.stack`` and the host-to-device copy
   ``serve.dispatch``          the jitted call, until it returns
   ``serve.to_host``           waiting for the kernel and copying back
-  ``serve.reply``             answers set, completion observers run,
-                              waiters woken
+  ``serve.reply``             answers set, the completion observer run
+                              once for the batch, waiters woken (arg
+                              ``events``)
   ==========================  =========================================
 """
 from __future__ import annotations

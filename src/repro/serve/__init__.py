@@ -125,14 +125,14 @@ class JetServer:
                  max_batch: int = 64,
                  window_us: float = 200.0,
                  interpret: Optional[bool] = None,
-                 on_done: Optional[Callable[[_Request], None]] = None):
+                 on_batch: Optional[Callable[[List[_Request]], None]] = None):
         self.qmlp, self.rho, self.agg = qmlp, rho, agg
         self.mode = mode
         self.max_batch = max_batch
         self.window_us = window_us
         self.interpret = (platform.interpret() if interpret is None
                           else interpret)
-        self.on_done = on_done
+        self.on_batch = on_batch
         self.stats = ServeStats()
         self._q: "queue.Queue[_Request]" = queue.Queue()
         self._step = 0
@@ -228,7 +228,7 @@ class JetServer:
                     continue
                 t_done = time.perf_counter()
                 self._shapes.add(size)
-                with span("serve.reply"):
+                with span("serve.reply", events=size):
                     self._reply(batch, out, t_done)
 
     def _reply(self, batch: List[_Request], out: np.ndarray,
@@ -237,15 +237,17 @@ class JetServer:
             r.result = out[i]
             r.t_done = t_done
             self.stats.record(r.t_submit, t_done)
-            if self.on_done is not None:
-                # Telemetry must never wedge the worker loop: a raising
-                # observer would strand every waiter on this queue.
-                try:
-                    self.on_done(r)
-                except Exception:
-                    pass
-            r.event.set()
         self.stats.batch_sizes.append(len(batch))
+        if self.on_batch is not None:
+            # Telemetry is recorded before any waiter wakes, and must never
+            # wedge the worker loop: a raising observer would strand every
+            # waiter on this queue.
+            try:
+                self.on_batch(batch)
+            except Exception:
+                pass
+        for r in batch:
+            r.event.set()
 
     # -- Tier-B modeled latency on the TPU target --------------------------------
     def modeled_latency_us(self) -> dict:

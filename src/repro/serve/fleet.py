@@ -188,16 +188,18 @@ class FleetServer:
             self._m_shed[t.name] = reg.counter("load.shed",
                                                {"tenant": t.name})
             for i, s in enumerate(servers):
-                s.on_done = self._replica_observer(t.name, i, s)
+                s.on_batch = self._replica_observer(t.name, i, s)
 
     def _replica_observer(self, tenant: str, i: int, server: JetServer):
-        """Per-replica completion hook run on the replica's worker thread.
+        """Per-replica completion hook run on the replica's worker thread,
+        once per served batch.
 
-        Streams the measured latency into the tenant's rolling histogram,
-        refreshes the queue-depth gauge, and feeds the drift monitor's
-        ``serve.latency_us`` stream for replica key ``tenant#i``. Distinct
-        replicas write distinct drift keys, so cross-thread writes never
-        touch the same entry.
+        Streams the batch's measured latencies and queue waits, in request
+        order, into the tenant's rolling histograms, counts the batch's
+        completions, refreshes the queue-depth gauge once after it, and
+        feeds the drift monitor's ``serve.latency_us`` stream for replica
+        key ``tenant#i``. Distinct replicas write distinct drift keys, so
+        cross-thread writes never touch the same entry.
         """
         lat = self.registry.histogram("fleet.request.latency_us",
                                       {"tenant": tenant})
@@ -209,14 +211,16 @@ class FleetServer:
         key = f"{tenant}#{i}"
         slo = self.slo_trackers.get(tenant)
 
-        def observe(req: _Request) -> None:
-            lat.record(req.latency_us)
-            wait.record(req.queue_wait_us)
-            done.inc()
+        def observe(batch: List[_Request]) -> None:
+            lats = [r.latency_us for r in batch]
+            lat.record_many(lats)
+            wait.record_many([r.queue_wait_us for r in batch])
+            done.inc(len(batch))
             depth.set(float(server._q.qsize()))
-            self.drift.observe(key, "serve.latency_us", req.latency_us)
+            self.drift.observe_many(key, "serve.latency_us", lats)
             if slo is not None:
-                slo.record(req.latency_us * 1e3)
+                for v in lats:
+                    slo.record(v * 1e3)
 
         return observe
 

@@ -316,6 +316,65 @@ class TestFleetTelemetry:
         assert fleet.drift.flagged(10.0, "serve.latency_us")
 
 
+    def test_batched_completion_telemetry_counts_every_event(self, qmlp):
+        """Mixed batches through two replicas: every served event lands
+        once in each histogram, completion counter and drift entry."""
+        q, jc = qmlp
+        fleet = FleetServer([TenantSpec(name="m", qmlp=q, mode="ref",
+                                        replicas=2)])
+        try:
+            xs = _events(jc, 40, q.e_in)
+            fleet.infer_batch(xs[:17])
+            for i in range(17, 21):
+                fleet.infer(xs[i])
+            fleet.infer_batch(xs[21:])
+            n = len(xs)
+            reg = fleet.registry
+            assert sum(fleet.stats("m").batch_sizes) == n
+            assert max(fleet.stats("m").batch_sizes) > 1
+            for name in ("fleet.request.latency_us",
+                         "fleet.request.queue_wait_us"):
+                h = reg.find(name, {"tenant": "m"})
+                assert h.count == sum(h.bucket_counts) == n
+            done = reg.all("fleet.replica.completed")
+            assert len(done) == 2 and sum(c.value for c in done) == n
+            entries = fleet.drift.entries("serve.latency_us")
+            assert {e.key for e in entries} <= {"m#0", "m#1"}
+            assert sum(e.count for e in entries) == n
+        finally:
+            fleet.close()
+
+    def test_batch_observer_runs_once_before_waiters_wake(self, qmlp):
+        """``on_batch`` sees each served batch once, with every answer set
+        and no waiter woken; one that raises still answers every waiter."""
+        from repro.serve import JetServer
+        q, jc = qmlp
+        calls = []
+
+        def observer(batch):
+            calls.append((list(batch),
+                          all(r.result is not None for r in batch),
+                          any(r.event.is_set() for r in batch)))
+            raise RuntimeError("observer bug")
+
+        srv = JetServer(q, mode="ref", max_batch=8, window_us=50_000,
+                        on_batch=observer)
+        ref = JetServer(q, mode="ref")
+        try:
+            xs = _events(jc, 12, q.e_in)
+            reqs = [srv.submit(x) for x in xs]
+            for r, x in zip(reqs, xs):
+                np.testing.assert_array_equal(r.wait(30), ref.infer(x))
+            assert [len(b) for b, _, _ in calls] == srv.stats.batch_sizes
+            assert ([id(r) for b, _, _ in calls for r in b]
+                    == [id(r) for r in reqs])
+            assert all(answered and not woken
+                       for _, answered, woken in calls)
+        finally:
+            srv.close()
+            ref.close()
+
+
 class TestLoadAndSLO:
     """Open-loop ingress: offer/shed accounting, SLO trackers, and the
     workload driver against a real (ref-mode) fleet."""

@@ -38,6 +38,130 @@ class TestP2Quantile:
             P2Quantile(1.0)
 
 
+def _p2_reference(p, xs):
+    """Textbook P² (Jain & Chlamtac 1985), one sample at a time over lists:
+    the reference the estimator's batched update must match bit for bit.
+    Returns (heights, positions, desired positions)."""
+    q = sorted(xs[:5])
+    n = [1.0, 2.0, 3.0, 4.0, 5.0]
+    np_ = [1.0, 1.0 + 2.0 * p, 1.0 + 4.0 * p, 3.0 + 2.0 * p, 5.0]
+    dn = [0.0, p / 2.0, p, (1.0 + p) / 2.0, 1.0]
+    for x in xs[5:]:
+        if x < q[0]:
+            q[0], k = x, 0
+        elif x >= q[4]:
+            q[4], k = x, 3
+        else:
+            k = next(i for i in range(4) if q[i] <= x < q[i + 1])
+        for i in range(k + 1, 5):
+            n[i] += 1.0
+        for i in range(5):
+            np_[i] += dn[i]
+        for i in (1, 2, 3):
+            d = np_[i] - n[i]
+            if ((d >= 1.0 and n[i + 1] - n[i] > 1.0)
+                    or (d <= -1.0 and n[i - 1] - n[i] < -1.0)):
+                d = 1.0 if d > 0 else -1.0
+                qp = q[i] + d / (n[i + 1] - n[i - 1]) * (
+                    (n[i] - n[i - 1] + d) * (q[i + 1] - q[i])
+                    / (n[i + 1] - n[i])
+                    + (n[i + 1] - n[i] - d) * (q[i] - q[i - 1])
+                    / (n[i] - n[i - 1]))
+                if not q[i - 1] < qp < q[i + 1]:
+                    j = i + int(d)
+                    qp = q[i] + d * (q[j] - q[i]) / (n[j] - n[i])
+                q[i] = qp
+                n[i] += d
+    return q, n, np_
+
+
+#: Batch splits: sizes cycled over the stream. "ragged" starts 2 + 4, so
+#: the first five (buffered) samples straddle a batch boundary, as do 3's.
+SPLITS = {"1": [1], "3": [3], "64": [64], "ragged": [2, 4, 1, 9, 64, 5, 33]}
+
+
+def _chunks(xs, split):
+    sizes, out, i, j = SPLITS[split], [], 0, 0
+    while i < len(xs):
+        k = sizes[j % len(sizes)]
+        out.append(xs[i:i + k])
+        i, j = i + k, j + 1
+    return out
+
+
+def _stream(n=4000, seed=11):
+    """Heavy-tailed latencies rounded to 0.1 so values tie with markers,
+    plus exact bucket bounds."""
+    rng = np.random.default_rng(seed)
+    xs = [round(float(v), 1) for v in rng.lognormal(4.0, 1.0, n)]
+    xs[100:106] = [1.0, 2.0, 5.0, 100.0, 0.001, 1e12]
+    return xs
+
+
+class TestBatchedRecording:
+    """``observe_many`` / ``record_many`` / drift ``observe_many`` leave the
+    state that recording one value at a time leaves."""
+
+    @pytest.mark.parametrize("split", list(SPLITS))
+    @pytest.mark.parametrize("p", [0.5, 0.9, 0.99])
+    def test_p2_observe_many_matches_per_sample(self, p, split):
+        xs = _stream()
+        batched = P2Quantile(p)
+        for chunk in _chunks(xs, split):
+            if split == "1":
+                batched.observe(chunk[0])
+            else:
+                batched.observe_many(chunk)
+        single = P2Quantile(p)
+        for x in xs:
+            single.observe(x)
+        ref = _p2_reference(p, xs)
+        assert (batched._q, batched._n, batched._np) == ref
+        assert (single._q, single._n, single._np) == ref
+        assert batched.value == single.value
+
+    @pytest.mark.parametrize("split", list(SPLITS))
+    def test_histogram_record_many_matches_per_sample(self, split):
+        xs = _stream()
+        batched, single = Histogram("lat", ()), Histogram("lat", ())
+        for chunk in _chunks(xs, split):
+            batched.record_many(chunk)
+        for x in xs:
+            single.record(x)
+        assert batched.count == single.count == len(xs)
+        assert batched.bucket_counts == single.bucket_counts
+        # bucket i holds bounds[i-1] < x <= bounds[i]; the last, overflow
+        expected = np.bincount(np.searchsorted(batched.bounds, xs,
+                                               side="left"),
+                               minlength=len(batched.bounds) + 1)
+        assert batched.bucket_counts == expected.tolist()
+        assert (batched.min, batched.max) == (single.min, single.max)
+        assert batched.sum == pytest.approx(single.sum, rel=1e-9)
+        for q, est in batched._p2.items():
+            other = single._p2[q]
+            assert (est._q, est._n, est._np) == (other._q, other._n,
+                                                 other._np)
+
+    def test_record_many_of_nothing_changes_nothing(self):
+        h = Histogram("lat", ())
+        h.record_many([])
+        assert h.count == 0 and h.min is None and h.as_dict()["buckets"] == []
+
+    @pytest.mark.parametrize("split", list(SPLITS))
+    def test_drift_observe_many_matches_observe(self, split):
+        xs = _stream(n=500)
+        batched, single = DriftMonitor(), DriftMonitor()
+        for chunk in _chunks(xs, split):
+            batched.observe_many("a#0", "serve.latency_us", chunk)
+        batched.observe_many("a#1", "serve.latency_us", [])
+        for x in xs:
+            single.observe("a#0", "serve.latency_us", x)
+        [b], [s] = batched.entries(), single.entries()
+        assert (b.key, b.count, b.last) == (s.key, s.count, s.last)
+        assert b.count == len(xs) and b.last == xs[-1]
+        assert b.total == pytest.approx(s.total, rel=1e-9)
+
+
 class TestHistogram:
     def test_streaming_quantiles_vs_numpy(self):
         rng = np.random.default_rng(7)

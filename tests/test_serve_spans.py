@@ -119,6 +119,11 @@ def test_one_step_per_served_batch(jet_trace):
     assert [s[4]["step"] for s in steps] == list(range(1, len(steps) + 1))
 
 
+def test_reply_tags_its_events(jet_trace):
+    replies = _named(jet_trace["spans"], "serve.reply")
+    assert [s[4]["events"] for s in replies] == jet_trace["batch_sizes"]
+
+
 def test_step_parts_nest_in_order_on_the_worker_line(jet_trace):
     spans = jet_trace["spans"]
     steps = _named(spans, "serve.step")
