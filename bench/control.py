@@ -5,11 +5,12 @@
 
 For each seed, in one process: build the cell's server, drive the cell's
 own traffic for ``--seconds``, then compare every answer with the plain
-int8 reference twice: once as served (the program's reading), once with the
-reference computed on the int4 grid put in the program's place (the
-control's reading). Prints one JSON row per seed. The benchmark's own runs
-never run the control; its readings set the upper end of the limits in
-PERF.md.
+reference, in the kind's precision, twice: once as served (the program's
+reading), once with the kind's ``control`` put in the program's place (the
+control's reading: the reference one precision below the configuration's,
+int4 for the int8 kinds). Prints one JSON row per seed. The benchmark's
+own runs never run the control; its readings set the upper end of the
+limits in PERF.md.
 """
 from __future__ import annotations
 
@@ -22,8 +23,8 @@ import numpy as np
 import harness
 
 
-def int4_answers(kind, cfg, model, pool):
-    return kind.reference(cfg, model, pool, int4=True)
+def control_answers(kind, cfg, model, pool):
+    return kind.control(cfg, model, pool)
 
 
 def main(argv=None) -> int:
@@ -38,7 +39,7 @@ def main(argv=None) -> int:
                 win = s.window(s.cell["traffic"], args.seconds, False,
                                np.random.default_rng([seed, 4]))
             program = harness.check(s, win["rec"])
-            control = harness.check(s, win["rec"], int4_answers)
+            control = harness.check(s, win["rec"], control_answers)
             print(json.dumps({
                 "seed": seed, "events": win["rec"].n,
                 "setup_s": s.setup_s, "warm": s.warmed,

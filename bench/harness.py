@@ -4,10 +4,16 @@
 harness finds everything else by those names:
 
   * ``bench/configs/<config>.json``: the sizes, and the model ``kind``;
-  * ``bench/kinds/<kind>.py``: builds the int8 weights and the program's
-    tenant from the configuration's ``weights_seed``, makes the inputs from
-    ``--seed``, computes the plain reference,
-    and counts the operations and bytes of a served call;
+  * ``bench/kinds/<kind>.py``: builds the weights in the configuration's
+    precision and the program's tenant from the configuration's
+    ``weights_seed``, makes the inputs from ``--seed``, computes the plain
+    reference and the control (``control``: the answers one precision
+    below the configuration's), counts the operations and bytes of a served
+    call, and names the ``repro.serve`` function that serves it
+    (``FAULT_SITE``, which the fault tests wrap). Optional: ``PEAK``, the
+    key of ``bench/peaks.json`` its operations are counted against
+    (``"int8_ops"`` by default), and ``mismatched(got, want)``, one flag
+    per event (exact equality of every element by default; see ``check``);
   * ``bench/traffic/<traffic>.json``: the mix's parameters, among them the
     ``generator``;
   * ``bench/generators/<generator>.py``: an open-loop ``schedule`` of arrival
@@ -20,7 +26,8 @@ A run builds one ``FleetServer`` with one replica at the program's defaults,
 warms every batch size its window can form through the public ``submit``,
 then drives the traffic for the window. Open-loop latency runs from each
 event's intended arrival to the moment the benchmark's collector holds the
-answer. After the window every answer is compared with the reference.
+answer. After the window every answer, kept in the reference's dtype, is
+compared with the reference.
 """
 from __future__ import annotations
 
@@ -143,6 +150,19 @@ def percentile(a, q: float) -> Optional[float]:
     return float(np.percentile(a, q)) if a.size else None
 
 
+def castable(frm: np.dtype, to: np.dtype) -> bool:
+    """Whether ``to`` holds every value of ``frm``. ``Record.take`` asks
+    once per event on the collector's thread, so equal dtypes are answered
+    first: ``np.can_cast`` costs about 20 times the comparison."""
+    return frm == to or np.can_cast(frm, to, "safe")
+
+
+def floating(dtype: np.dtype) -> bool:
+    """Answers that are neither integers nor booleans (ml_dtypes' bfloat16
+    has the kind ``V``, so numpy's ``floating`` would miss it)."""
+    return np.dtype(dtype).kind not in "biu"
+
+
 def warm(fleet, name: str, pool: np.ndarray, max_batch: int,
          log: Callable[[str], None]) -> dict:
     """Serve every batch size 1..max_batch through ``FleetServer.submit``.
@@ -185,20 +205,24 @@ class Record:
 
     Each event's input is ``pool[idx[i]]``, drawn from the seed. A closed
     loop does not know its count ahead, so the arrays double when full.
+    Answers are kept in ``dtype``, the reference's; an answer that dtype
+    cannot hold safely is not kept but flagged in ``uncastable``.
     """
 
     FIELDS = ("due", "offer_us", "lag_us", "got", "t_submit", "t_start",
               "t_done")
 
-    def __init__(self, n: int, out_shape: tuple, rng, pool_size: int):
+    def __init__(self, n: int, out_shape: tuple, dtype, rng,
+                 pool_size: int):
         self.n, self.cap = 0, 0
         self.rng, self.pool_size = rng, pool_size
-        self.out_shape = tuple(out_shape)
+        self.out_shape, self.dtype = tuple(out_shape), np.dtype(dtype)
         self.error: Optional[BaseException] = None
         for f in self.FIELDS:
             setattr(self, f, np.empty(0))
         self.idx = np.empty(0, np.int64)
-        self.answers = np.empty((0,) + self.out_shape, np.int8)
+        self.answers = np.empty((0,) + self.out_shape, self.dtype)
+        self.uncastable = np.empty(0, bool)
         self.ensure(n)
 
     def ensure(self, n: int) -> None:
@@ -211,7 +235,9 @@ class Record:
         self.idx = np.concatenate(
             [self.idx, self.rng.integers(0, self.pool_size, add)])
         self.answers = np.concatenate(
-            [self.answers, np.zeros((add,) + self.out_shape, np.int8)])
+            [self.answers, np.zeros((add,) + self.out_shape, self.dtype)])
+        self.uncastable = np.concatenate(
+            [self.uncastable, np.zeros(add, bool)])
         self.cap += add
 
     def take(self, i: int, req, t_got: float) -> None:
@@ -223,7 +249,13 @@ class Record:
         self.t_submit[i] = req.t_submit
         self.t_start[i] = req.t_start
         self.t_done[i] = req.t_done
-        self.answers[i] = req.result
+        result = np.asarray(req.result)
+        # Assignment would cast unsafely without a word (a float logit into
+        # int8 truncates), so such an answer is a mismatch, never kept.
+        if castable(result.dtype, self.dtype):
+            self.answers[i] = result
+        else:
+            self.uncastable[i] = True
 
 
 def _wait(req, deadline: list) -> bool:
@@ -405,8 +437,8 @@ class Session:
         self.model = kind.build(cfg, cfg["weights_seed"])
         self.pool = kind.inputs(cfg, self.model, self.pool_size,
                                 seed=[self.seed, 3])
-        self.out_shape = tuple(kind.reference(cfg, self.model,
-                                              self.pool[:1]).shape[1:])
+        ref = kind.reference(cfg, self.model, self.pool[:1])
+        self.out_shape, self.out_dtype = tuple(ref.shape[1:]), ref.dtype
         self.name = cfg["name"]
         opts = {} if self.max_batch is None else {"max_batch": self.max_batch}
         self.fleet = FleetServer([kind.tenant(cfg, self.model, self.name)],
@@ -438,8 +470,8 @@ class Session:
         span = (jax.profiler.TraceAnnotation if trace
                 else (lambda _name: contextlib.nullcontext()))
         offsets = gen.schedule(traffic, seconds, rng) if gen.OPEN_LOOP else []
-        rec = Record(len(offsets) or 1 << 16, self.out_shape, rng,
-                     self.pool_size)
+        rec = Record(len(offsets) or 1 << 16, self.out_shape,
+                     self.out_dtype, rng, self.pool_size)
         before = len(self.fleet.stats(self.name).batch_sizes)
         with profiled(trace) as prof, CompileClock() as clock:
             if gen.OPEN_LOOP:
@@ -483,33 +515,82 @@ def timings(s: Session, win: dict) -> types.SimpleNamespace:
         batch_sizes=win["batch_sizes"],
         ops_per_event=2 * kind.macs_per_event(cfg),
         min_bytes=lambda b: kind.min_bytes(cfg, b),
-        peaks=s.peaks, trace=win["trace"])
+        peaks=s.peaks, trace=win["trace"],
+        peak_ops=(s.peaks[getattr(kind, "PEAK", "int8_ops")]
+                  if s.peaks else None))
 
 
 def check(s: Session, rec: Record, answers_from: Optional[Callable] = None
           ) -> dict:
-    """Every answer of the window against the plain reference.
+    """Every answer of the window against the plain reference, in the
+    kind's precision.
+
+    An event is mismatched where the kind's ``mismatched(got, want)`` flags
+    it (without one, where any element differs from the reference), where
+    its answer has a dtype the reference's cannot hold safely, or where any
+    element of a floating answer is not finite, whatever the kind says.
+    A float kind states in ``mismatched`` a tolerance for each quantity,
+    each written with its reason and tight enough that the kind's
+    ``control`` fails it.
 
     ``answers_from(kind, cfg, model, pool)`` puts other answers in the
     program's place (the control, and the tests' faults).
     """
     n = rec.n
     answered = ~np.isnan(rec.got[:n])
-    want = s.kind.reference(s.cfg, s.model, s.pool)
-    got = (answers_from(s.kind, s.cfg, s.model, s.pool)[rec.idx[:n]]
-           if answers_from is not None else rec.answers[:n])
-    flat = lambda a: a.reshape(a.shape[0], -1)
-    mismatched = int((flat(got[answered])
-                      != flat(want[rec.idx[:n][answered]])).any(axis=1).sum())
+    idx = rec.idx[:n][answered]
+    want = s.kind.reference(s.cfg, s.model, s.pool)[idx]
     served = rec.answers[:n][answered]
+    uncastable = rec.uncastable[:n][answered]
+    if answers_from is None:
+        got, bad = served, uncastable
+    else:
+        got = np.asarray(answers_from(s.kind, s.cfg, s.model, s.pool))[idx]
+        bad = np.full(len(got), not castable(got.dtype, want.dtype))
+    bad = bad | mismatched(s.kind, got, want)
     return {"checks": {
-                "mismatched_events": {"value": mismatched, "limit": 0},
+                "mismatched_events": {"value": int(bad.sum()), "limit": 0},
                 "unanswered_events": {"value": int(n - answered.sum()),
                                       "limit": 0}},
-            "answers_spread": {
-                "zero": float(np.mean(served == 0)) if served.size else None,
-                "clipped": float(np.mean((served == 127) | (served == -128)))
-                if served.size else None}}
+            "answers_spread": answers_spread(served[~uncastable],
+                                             want[~uncastable])}
+
+
+def mismatched(kind, got: np.ndarray, want: np.ndarray) -> np.ndarray:
+    """One flag per event: the kind's rule, or exact equality of every
+    element; and any non-finite element of a floating answer."""
+    flat = lambda a: a.reshape(len(a), -1)
+    rule = getattr(kind, "mismatched", None)
+    if rule is None:
+        bad = (flat(got) != flat(want)).any(axis=1)
+    else:
+        bad = np.asarray(rule(got, want), bool)
+        if bad.shape != (len(got),):
+            raise ValueError(f"the kind's mismatched gave shape "
+                             f"{bad.shape} for {len(got)} events")
+    if floating(got.dtype):
+        bad = bad | ~np.isfinite(flat(got)).all(axis=1)
+    return bad
+
+
+def answers_spread(served: np.ndarray, want: np.ndarray) -> dict:
+    """How the served answers spread: the share at zero, and for integers
+    the share at the dtype's ends, for floats the share not finite and the
+    largest finite |served - want|."""
+    if not served.size:
+        return ({"zero": None, "nonfinite": None, "max_abs_diff": None}
+                if floating(served.dtype) else
+                {"zero": None, "clipped": None})
+    zero = float(np.mean(served == 0))
+    if not floating(served.dtype):
+        info = np.iinfo(served.dtype)
+        return {"zero": zero, "clipped": float(np.mean(
+            (served == info.max) | (served == info.min)))}
+    finite = np.isfinite(served)
+    diff = np.abs(served.astype(np.float64) - want.astype(np.float64))
+    return {"zero": zero, "nonfinite": float(np.mean(~finite)),
+            "max_abs_diff": float(diff[finite].max()) if finite.any()
+            else None}
 
 
 def window_details(win: dict) -> dict:

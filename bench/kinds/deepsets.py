@@ -10,6 +10,9 @@ import numpy as np
 import intquant
 import jets
 
+#: The ``repro.serve`` function that serves this kind; the fault tests wrap it.
+FAULT_SITE = "deepsets"
+
 
 def build(cfg: dict, seed: int) -> dict:
     """The int8 weights, calibrated on a seeded jet batch."""
@@ -49,6 +52,11 @@ def reference(cfg: dict, model: dict, x: np.ndarray, *,
     """(n, 1, classes) int8 for (n, constituents, features) int8."""
     h = intquant.mlp(x, model["phi"], int4=int4)
     return intquant.mlp(intquant.mean_over_set(h), model["rho"], int4=int4)
+
+
+def control(cfg: dict, model: dict, x: np.ndarray) -> np.ndarray:
+    """The reference on the int4 grid, one precision below int8."""
+    return reference(cfg, model, x, int4=True)
 
 
 def macs_per_event(cfg: dict) -> int:

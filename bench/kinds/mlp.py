@@ -11,6 +11,9 @@ import numpy as np
 import intquant
 import jets
 
+#: The ``repro.serve`` function that serves this kind; the fault tests wrap it.
+FAULT_SITE = "cascade_mlp"
+
 
 def build(cfg: dict, seed: int) -> dict:
     rng = np.random.default_rng([seed, 1])
@@ -39,6 +42,11 @@ def reference(cfg: dict, model: dict, x: np.ndarray, *,
               int4: bool = False) -> np.ndarray:
     """(n, rows, classes) int8 for (n, rows, features) int8."""
     return intquant.mlp(x, model["layers"], int4=int4)
+
+
+def control(cfg: dict, model: dict, x: np.ndarray) -> np.ndarray:
+    """The reference on the int4 grid, one precision below int8."""
+    return reference(cfg, model, x, int4=True)
 
 
 def macs_per_event(cfg: dict) -> int:

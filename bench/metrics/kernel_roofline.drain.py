@@ -1,6 +1,7 @@
 """The served kernel's share of its roofline: the least time the chip
-could take for the window's calls, the larger of int8 operations over the
-int8 peak and bytes over the HBM peak, over the kernel's device time."""
+could take for the window's calls, the larger of operations over the peak
+the kind names (``ctx.peak_ops``) and bytes over the HBM peak, over the
+kernel's device time."""
 import harness
 
 
@@ -9,7 +10,7 @@ def read(ctx):
     if not calls or not ctx.peaks or not ctx.batch_sizes:
         return None
     p = ctx.peaks
-    least_s = sum(max(b * ctx.ops_per_event / p["int8_ops"],
+    least_s = sum(max(b * ctx.ops_per_event / ctx.peak_ops,
                       ctx.min_bytes(b) / p["hbm_bytes_per_s"])
                   for b in ctx.batch_sizes)
     # One kernel call serves one batch; scale to the calls the trace saw.

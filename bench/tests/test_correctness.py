@@ -42,7 +42,7 @@ def test_sound_run_is_correct(workload):
 def test_int4_control_is_not_correct(workload):
     sys.path.insert(0, str(BENCH))
     import control
-    result = _run(workload, answers_from=control.int4_answers)
+    result = _run(workload, answers_from=control.control_answers)
     assert not result["correct"]
     assert result["checks"]["mismatched_events"]["value"] > 0
 
@@ -66,8 +66,7 @@ def _half_set(f):
 @pytest.mark.parametrize("workload", CELLS)
 def test_broken_timed_path_is_not_correct(workload, fault, monkeypatch):
     import repro.serve as serve
-    kernel = ("deepsets" if harness.resolve(workload)["config"]["kind"]
-              == "deepsets" else "cascade_mlp")
+    kernel = harness.resolve(workload)["kind"].FAULT_SITE
     monkeypatch.setattr(serve, kernel, fault(getattr(serve, kernel)))
     result = _run(workload)
     assert not result["correct"]
