@@ -4,8 +4,9 @@ Accepts the model-layout tensors (B, S, H, hd) / (B, T, KV, hd), repeats KV
 groups, collapses batch x heads, pads sequence lengths to the block grid,
 and slices back. Padded key rows are masked by construction for the causal
 case (pad queries attend only to themselves; their output rows are sliced
-off) — for the non-causal case an explicit length mask would be needed, so
-ops only exposes causal=True (the LM serving path).
+off). The non-causal entry, ``flash_mha_bias``, takes head-major tensors,
+an additive bias and a key mask (the Particle Transformer's attention);
+its caller pads, and masks the padded keys through that mask.
 """
 from __future__ import annotations
 
@@ -48,3 +49,28 @@ def flash_mha(q: jax.Array, k: jax.Array, v: jax.Array, *,
     out = out[:, :S]
     return out.reshape(B, H, S, hd).transpose(0, 2, 1, 3).reshape(B, S,
                                                                   H * hd)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def flash_mha_bias(q: jax.Array, k: jax.Array, v: jax.Array,
+                   bias: jax.Array, kv_mask: jax.Array, *,
+                   interpret: bool = False) -> jax.Array:
+    """Non-causal flash attention with a per-head additive bias and a key
+    mask. q/k/v (B, H, S, hd), bias (B, H, S, S) added after the 1/sqrt(hd)
+    scaling, kv_mask (B, S) (nonzero = a real key). Returns (B, H, S, hd).
+
+    One grid step holds all H heads of a sequence: up to 128 positions the
+    block is the whole (S x S) tile, beyond that S is a multiple of 128 and
+    the blocks are 128 x 128. Padded positions are the caller's, masked
+    through ``kv_mask``.
+    """
+    B, H, S, hd = q.shape
+    block = min(S, 128)
+    out = flash_attention(
+        *(t.reshape(B * H, S, hd) for t in (q, k, v)), causal=False,
+        block_q=block, block_k=block, block_b=H,
+        bias=bias.reshape(B * H, S, S),
+        kv_mask=jnp.broadcast_to(kv_mask[:, None, :], (B, H, S)).reshape(
+            B * H, S),
+        interpret=interpret)
+    return out.reshape(B, H, S, hd)

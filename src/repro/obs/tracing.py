@@ -55,6 +55,28 @@ Served-path spans (:func:`span`), by thread:
                               once for the batch, waiters woken (arg
                               ``events``)
   ==========================  =========================================
+
+Inside ``serve.dispatch`` the device runs the served program. Its kernels
+are named ``pallas_call``s, so the device trace names each call by its HLO
+instruction; the Particle Transformer's stages are ``jax.named_scope``s,
+which reach the HLO metadata (``op_name``) and the profile:
+
+  ==========================  =========================================
+  name                        covers
+  ==========================  =========================================
+  ``cascade_mlp``, ``deepsets``  the int8 taggers' fused kernels
+                              (``%vmap_cascade_mlp_.1``,
+                              ``%vmap_deepsets_.1`` in the trace)
+  ``flash_attn``              the attention kernel, one call per ParT
+                              particle block and batch
+                              (``%flash_attn.<n>``)
+  ``part.embed``              ParT: particle embedding
+  ``part.pair_features``      the four pair features of every pair
+  ``part.pair_embed``         the pair embedding to the bias U
+  ``part.block{i}``           particle blocks 0..7 (attention with U)
+  ``part.cls_block{i}``       class-attention blocks 0..1
+  ``part.head``               the final LayerNorm and class logits
+  ==========================  =========================================
 """
 from __future__ import annotations
 

@@ -19,7 +19,7 @@ import dataclasses
 import queue
 import threading
 import time
-from typing import Callable, List, Optional, Sequence, Tuple
+from typing import Any, Callable, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -33,6 +33,36 @@ from repro.obs import span
 from repro.quant import QuantizedMLP, quantize_pow2
 from repro.kernels.cascade_mlp import (cascade_mlp, cascade_mlp_ref, deepsets,
                                        deepsets_ref, mlp_unfused)
+from repro.models.part import part_forward, serving_params
+
+
+@dataclasses.dataclass(frozen=True)
+class ServedModel:
+    """A served model other than the int8 MLP and DeepSets.
+
+    ``fn(event, params)`` answers one event, the event first as every
+    served function of this module takes it. :class:`JetServer` batches it
+    with ``jax.vmap`` over events (``params`` shared) and compiles it with
+    ``jax.jit`` for power-of-two batch sizes; ``params``, a pytree of
+    arrays, are arguments of those programs and not constants compiled into
+    them, so new weights need no new compile.
+    """
+
+    fn: Callable[[Any, Any], Any]
+    params: Any
+
+
+def part_jet(event, params):
+    """One Particle Transformer jet: ``models.part.part_forward`` taking the
+    event first, the order the served-model convention and the fault tests
+    (which wrap this global) rely on."""
+    return part_forward(params, event)
+
+
+def part_model(params) -> ServedModel:
+    """ParT served from float32 ``params`` (``models.part`` layout), its
+    matrix weights held in bfloat16."""
+    return ServedModel(fn=part_jet, params=serving_params(params))
 
 
 @dataclasses.dataclass
@@ -110,23 +140,29 @@ class _Request:
 
 
 class JetServer:
-    """Batching inference server for quantized MLP / DeepSets jet taggers.
+    """Batching inference server for jet taggers: a quantized MLP or
+    DeepSets (``qmlp``, ``rho``), or any other :class:`ServedModel`
+    (``model``); exactly one of ``qmlp`` and ``model``.
 
-    ``mode``: 'fused' (single cascade kernel), 'unfused' (per-layer chain),
-    'ref' (pure-jnp oracle; used in tests for bit-identical checks).
-    ``interpret`` defaults to what the platform needs
+    ``mode`` (int8 taggers): 'fused' (single cascade kernel), 'unfused'
+    (per-layer chain), 'ref' (pure-jnp oracle; used in tests for
+    bit-identical checks). ``interpret`` defaults to what the platform needs
     (:func:`repro.launch.platform.interpret`): compiled kernels on a TPU.
     """
 
-    def __init__(self, qmlp: QuantizedMLP, *,
+    def __init__(self, qmlp: Optional[QuantizedMLP] = None, *,
                  rho: Optional[QuantizedMLP] = None,
                  agg: str = "mean",
                  mode: str = "fused",
+                 model: Optional[ServedModel] = None,
                  max_batch: int = 64,
                  window_us: float = 200.0,
                  interpret: Optional[bool] = None,
                  on_batch: Optional[Callable[[List[_Request]], None]] = None):
+        if (qmlp is None) == (model is None):
+            raise ValueError("give exactly one of qmlp and model")
         self.qmlp, self.rho, self.agg = qmlp, rho, agg
+        self.model = model
         self.mode = mode
         self.max_batch = max_batch
         self.window_us = window_us
@@ -145,6 +181,8 @@ class JetServer:
 
     # -- model function -------------------------------------------------------
     def _build(self) -> Callable[[jnp.ndarray], jnp.ndarray]:
+        if self.model is not None:
+            return self._build_model()
         is_deepsets = self.rho is not None
         if is_deepsets:
             # DeepSets consumes one event (M, F) at a time; vmap batches events.
@@ -165,6 +203,26 @@ class JetServer:
                 f = lambda x: cascade_mlp_ref(x, self.qmlp)
             fn = jax.jit(jax.vmap(f))
         return fn
+
+    def _build_model(self) -> Callable[[jnp.ndarray], jnp.ndarray]:
+        """A :class:`ServedModel`'s batched call. Its program is compiled
+        for the power-of-two batch sizes up to ``max_batch`` (and
+        ``max_batch``) alone: a batch between two is padded with zero events
+        to the next and the answers sliced back, so a large model compiles
+        a handful of programs, not one per batch size."""
+        fn = jax.jit(jax.vmap(self.model.fn, in_axes=(0, None)))
+        params = jax.device_put(self.model.params)
+        sizes = sorted({min(1 << k, self.max_batch)
+                        for k in range(self.max_batch.bit_length() + 1)})
+
+        def call(xs: jnp.ndarray) -> jnp.ndarray:
+            n = xs.shape[0]
+            size = next(b for b in sizes if b >= n)
+            if size == n:
+                return fn(xs, params)
+            pad = [(0, size - n)] + [(0, 0)] * (xs.ndim - 1)
+            return fn(jnp.pad(xs, pad), params)[:n]
+        return call
 
     # -- public API ------------------------------------------------------------
     def submit(self, x: np.ndarray) -> _Request:

@@ -43,7 +43,7 @@ from repro.core.layerspec import ModelSpec
 from repro.obs import DriftMonitor, MetricsRegistry, span
 from repro.obs.slo import SLOReport, SLOSpec, SLOTracker
 from repro.quant import QuantizedMLP
-from repro.serve import JetServer, ServeStats, _Request
+from repro.serve import JetServer, ServedModel, ServeStats, _Request
 
 
 @dataclasses.dataclass
@@ -83,18 +83,22 @@ class BatchResult:
 class TenantSpec:
     """One model deployed on the fleet with ``replicas`` independent copies.
 
-    ``model_spec`` (the Tier-A :class:`ModelSpec`) is optional; when given,
+    The model is an int8 MLP or DeepSets (``qmlp``, ``rho``, ``agg``,
+    ``mode``) or any other :class:`repro.serve.ServedModel` (``model``);
+    exactly one of ``qmlp`` and ``model``. ``model_spec`` (the Tier-A
+    :class:`ModelSpec`) is optional; when given,
     :meth:`FleetServer.modeled_throughput` packs the same replica count onto
     the modeled VEK280 array for the hardware-side comparison.
     """
 
     name: str
-    qmlp: QuantizedMLP
+    qmlp: Optional[QuantizedMLP] = None
     rho: Optional[QuantizedMLP] = None
     agg: str = "mean"
     mode: str = "fused"
     replicas: int = 1
     model_spec: Optional[ModelSpec] = None
+    model: Optional[ServedModel] = None
 
 
 class FleetServer:
@@ -153,6 +157,9 @@ class FleetServer:
                 raise ValueError(f"duplicate tenant {t.name!r}")
             if t.replicas < 1:
                 raise ValueError(f"tenant {t.name!r}: replicas must be >= 1")
+            if (t.qmlp is None) == (t.model is None):
+                raise ValueError(f"tenant {t.name!r}: give exactly one of "
+                                 f"qmlp and model")
             seen.add(t.name)
         for name in self.slo_trackers:
             if name not in seen:
@@ -161,6 +168,7 @@ class FleetServer:
             self.tenants[t.name] = t
             servers = [
                 JetServer(t.qmlp, rho=t.rho, agg=t.agg, mode=t.mode,
+                          model=t.model,
                           max_batch=max_batch, window_us=window_us)
                 for _ in range(t.replicas)]
             self._servers[t.name] = servers

@@ -7,7 +7,7 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.kernels.flash_attn import (flash_attention, flash_attention_ref,
-                                      flash_mha)
+                                      flash_mha, flash_mha_bias)
 
 
 def _rand(rng, shape, dtype):
@@ -41,6 +41,58 @@ class TestFlashAttention:
         q, k, v = (_rand(rng, (2, 256, 64), jnp.float32) for _ in range(3))
         out = flash_attention(q, k, v, causal=False, interpret=True)
         ref = flash_attention_ref(q, k, v, causal=False)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=2e-5, rtol=2e-5)
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("with_bias,with_mask", [
+        (True, False), (False, True), (True, True)],
+        ids=["bias", "mask", "both"])
+    def test_bias_and_key_mask(self, dtype, with_bias, with_mask):
+        """ParT's shape: 8 heads of 16 over S = T = 128, one grid step."""
+        rng = np.random.default_rng(11)
+        BH, S, d = 8, 128, 16
+        q, k, v = (_rand(rng, (BH, S, d), dtype) for _ in range(3))
+        bias = (_rand(rng, (BH, S, S), jnp.float32) if with_bias
+                else None)
+        mask = (jnp.asarray(rng.random((BH, S)) < 0.6) if with_mask
+                else None)
+        out = flash_attention(q, k, v, causal=False, block_b=BH, bias=bias,
+                              kv_mask=mask, interpret=True)
+        ref = flash_attention_ref(q, k, v, causal=False, bias=bias,
+                                  kv_mask=mask)
+        np.testing.assert_allclose(
+            np.asarray(out, np.float32), np.asarray(ref, np.float32),
+            atol=TOL[dtype], rtol=TOL[dtype])
+
+    def test_fully_masked_row_is_finite(self):
+        """Every key of one head masked: the row averages V, never NaN."""
+        rng = np.random.default_rng(12)
+        q, k, v = (_rand(rng, (2, 128, 16), jnp.float32) for _ in range(3))
+        bias = _rand(rng, (2, 128, 128), jnp.float32)
+        mask = jnp.ones((2, 128), bool).at[1].set(False)
+        out = np.asarray(flash_attention(q, k, v, causal=False, block_b=2,
+                                         bias=bias, kv_mask=mask,
+                                         interpret=True))
+        assert np.isfinite(out).all()
+        np.testing.assert_allclose(
+            out[1], np.broadcast_to(np.asarray(v[1]).mean(0), (128, 16)),
+            atol=2e-5, rtol=2e-5)
+
+    @pytest.mark.parametrize("S", [16, 256])
+    def test_biased_wrapper(self, S):
+        """flash_mha_bias == oracle: one whole tile (16), and two kv blocks
+        carried through the online softmax (256)."""
+        rng = np.random.default_rng(S)
+        B, H, hd = 2, 8, 16
+        q, k, v = (_rand(rng, (B, H, S, hd), jnp.float32) for _ in range(3))
+        bias = _rand(rng, (B, H, S, S), jnp.float32)
+        mask = jnp.asarray(rng.random((B, S)) < 0.7)
+        out = flash_mha_bias(q, k, v, bias, mask, interpret=True)
+        ref = flash_attention_ref(
+            *(t.reshape(B * H, S, hd) for t in (q, k, v)), causal=False,
+            bias=bias.reshape(B * H, S, S),
+            kv_mask=jnp.repeat(mask, H, axis=0)).reshape(B, H, S, hd)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-5, rtol=2e-5)
 

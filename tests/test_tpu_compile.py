@@ -140,3 +140,23 @@ def test_kernel_is_named_in_the_compiled_program(compile_text, name):
     calls = [ln.split(" = ")[0].strip() for ln in text.splitlines()
              if "custom_call_target=\"tpu_custom_call\"" in ln]
     assert calls and all(name in c for c in calls), calls
+
+
+def test_part_compiles_with_its_named_kernel(compile_text, one_chip,
+                                             monkeypatch):
+    """The served ParT at its published widths, 128 particles, a batch of
+    64: the attention kernel compiles for the chip (interpret mode off, as
+    on a TPU) and is the program's only kernel, once per particle block."""
+    from repro.launch import platform
+    from repro.models import part
+    from repro.serve import part_jet
+    monkeypatch.setattr(platform, "interpret", lambda: False)
+    spec = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    params = jax.tree.map(spec, part.serving_params(part.init_params(0)))
+    event = jax.ShapeDtypeStruct((64, 128, part.N_COLUMNS), jnp.float32,
+                                 sharding=one_chip)
+    text = jax.jit(jax.vmap(part_jet, in_axes=(0, None))).lower(
+        event, params).compile().as_text()
+    calls = [ln.split(" = ")[0].strip() for ln in text.splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in ln]
+    assert len(calls) == 8 and all("flash_attn" in c for c in calls), calls
